@@ -4,7 +4,8 @@ from __future__ import annotations
 
 import numpy as np
 
-from benchmarks.common import QUICK_SCALE, print_table, record_trajectory, timeit
+from benchmarks.common import (QUICK_SCALE, enable_cache, print_table,
+                               record_trajectory, timeit)
 from repro.core.config import ServingConfig
 from repro.core.engine import DecoupledEngine
 from repro.gnn.model import GNNConfig
@@ -36,4 +37,5 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
+    enable_cache()
     run(quick=False)

@@ -52,7 +52,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np
 
-from benchmarks.common import print_table, record_trajectory
+from benchmarks.common import enable_cache, print_table, record_trajectory
 from repro.core.config import ServingConfig
 from repro.core.engine import DecoupledEngine
 from repro.gnn.model import GNNConfig
@@ -294,6 +294,7 @@ def run_suite(quick: bool = True):
 
 
 if __name__ == "__main__":
+    enable_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=2048)
     ap.add_argument("--batch-size", type=int, default=8)

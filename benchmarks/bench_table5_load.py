@@ -12,7 +12,8 @@ import time
 import jax
 import numpy as np
 
-from benchmarks.common import QUICK_SCALE, print_table, record_trajectory
+from benchmarks.common import (QUICK_SCALE, enable_cache, print_table,
+                               record_trajectory)
 from repro.core.subgraph import build_batch
 from repro.graphs.synthetic import get_graph
 
@@ -68,4 +69,5 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
+    enable_cache()
     run(quick=False)

@@ -7,7 +7,8 @@ import time
 
 import numpy as np
 
-from benchmarks.common import QUICK_SCALE, print_table, record_trajectory
+from benchmarks.common import (QUICK_SCALE, enable_cache, print_table,
+                               record_trajectory)
 from repro.core.ini import ini_batch, select_important
 from repro.graphs.synthetic import get_graph
 
@@ -38,4 +39,5 @@ def run(quick: bool = True):
 
 
 if __name__ == "__main__":
+    enable_cache()
     run(quick=False)

@@ -19,6 +19,7 @@ from benchmarks import (bench_eq1_loadbalance, bench_fig3_breakdown,
                         bench_precompute, bench_program, bench_rpc,
                         bench_serve_multimodel, bench_shard,
                         bench_store, bench_table5_load, bench_table6_ini)
+from benchmarks.common import enable_cache
 
 SUITES = {
     "fig8_latency": bench_fig8_latency.run,
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--full", action="store_true")
     ap.add_argument("--only", default=None)
     args = ap.parse_args()
+    enable_cache()
     names = [args.only] if args.only else list(SUITES)
     failed = []
     for name in names:

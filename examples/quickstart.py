@@ -7,12 +7,18 @@ graph: PPR important-neighbor identification on the host, fixed-shape
 subgraph batches, and the jitted ACK inference program, with the
 triple-buffered host/device pipeline hiding preparation latency.
 """
+import os
+
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.config import ServingConfig
 from repro.core.engine import DecoupledEngine
 from repro.gnn.model import GNNConfig
 from repro.graphs.synthetic import get_graph
+
+enable_compile_cache(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 # 1. graph (synthetic stand-in for Flickr: 500-dim features, power-law)
 g = get_graph("flickr", scale=0.05, seed=0)

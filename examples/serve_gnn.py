@@ -5,16 +5,21 @@ percentiles — the 'latency per batch' metric of paper §3.1/§5.3.
     PYTHONPATH=src python examples/serve_gnn.py [--requests 512]
 """
 import argparse
+import os
 import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.config import ServingConfig
 from repro.core.engine import DecoupledEngine
 from repro.gnn.model import GNNConfig
 from repro.gnn.train import train_gnn
 from repro.graphs.synthetic import get_graph
 from repro.serve.gnn_server import GNNServer
+
+enable_compile_cache(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--requests", type=int, default=256)

@@ -11,15 +11,20 @@ per-model micro-batchers that stream into each engine's persistent
 pipeline; the report shows per-model tail latency and overlap.
 """
 import argparse
+import os
 import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.config import ServingConfig
 from repro.core.engine import DecoupledEngine
 from repro.gnn.model import GNNConfig
 from repro.graphs.synthetic import get_graph
 from repro.serve.gnn_server import GNNServer
+
+enable_compile_cache(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--requests", type=int, default=300)

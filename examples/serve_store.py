@@ -11,16 +11,21 @@ local push. ``invalidate()`` shows the graph-update hook forcing a
 recompute for affected targets.
 """
 import argparse
+import os
 import time
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core.config import ServingConfig
 from repro.core.engine import DecoupledEngine
 from repro.gnn.model import GNNConfig
 from repro.graphs.synthetic import get_graph, zipf_traffic
 from repro.serve.gnn_server import GNNServer
 from repro.store import StorePolicy
+
+enable_compile_cache(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--requests", type=int, default=400)

@@ -4,12 +4,17 @@ the paper's accelerator serves), a few hundred steps on CPU.
     PYTHONPATH=src python examples/train_gnn.py [--steps 200]
 """
 import argparse
+import os
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.gnn.model import GNNConfig
 from repro.gnn.train import train_gnn
 from repro.graphs.synthetic import get_graph
+
+enable_compile_cache(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--steps", type=int, default=200)

@@ -171,6 +171,10 @@ class VariantCache:
         with self._lock:
             return list(self._entries)
 
+    def items(self) -> List[Tuple[Tuple, Callable]]:
+        with self._lock:
+            return list(self._entries.items())
+
     def stats(self) -> dict:
         with self._lock:
             return {"capacity": self.capacity, "size": len(self._entries),

@@ -39,13 +39,24 @@ TPU_OPS = {"matmul", "add", "relu", "mul", "exp", "max", "leaky_relu",
 
 @dataclass(frozen=True)
 class TPUSpec:
+    """One chip's published limits (Google Cloud "TPU v5e" page). Every
+    admission check plans against it, so a process that serves on some
+    other chip must say so: ``check_device`` refuses a mismatch."""
     name: str = "tpu-v5e"
+    device_kind: str = "TPU v5 lite"    # jax Device.device_kind of the chip
     peak_flops: float = 197e12          # bf16
     hbm_bw: float = 819e9               # bytes/s
     vmem_bytes: int = 16 * 2 ** 20      # per-core VMEM budget for the plan
     hbm_bytes: int = 16 * 2 ** 30
     ici_bw: float = 50e9                # per link
     mxu: int = MXU_LANE
+
+    def check_device(self, device) -> None:
+        """Raise unless ``device`` is the chip this spec describes."""
+        if device.device_kind != self.device_kind:
+            raise RuntimeError(
+                f"{self.name} spec describes {self.device_kind!r}, but the "
+                f"device is {device.device_kind!r} ({device.platform})")
 
 
 @dataclass

@@ -22,6 +22,7 @@ from __future__ import annotations
 import functools
 import threading
 import time
+import warnings
 from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass
 from typing import Dict, Optional
@@ -82,6 +83,11 @@ class DecoupledEngine:
             self.tracer = None
             self._calib = None
         self._calib_count = 0
+        # failed calibration / exploration passes: serving continues on
+        # the jitted program, but every failure is counted (dispatch
+        # report + repro_exploration_errors_total) and warned, never
+        # swallowed
+        self.exploration_errors = 0
         # live telemetry plane (same contract: off by default, every
         # hot-path site guards on ``telemetry is None``)
         if config.telemetry is not None:
@@ -315,6 +321,10 @@ class DecoupledEngine:
             reg.counter_fn("repro_store_resident_lookups_total",
                            lambda: src.resident_lookups,
                            help="feature rows served device-resident")
+        reg.counter_fn("repro_exploration_errors_total",
+                       lambda: self.exploration_errors,
+                       help="failed calibration/exploration/autotune "
+                            "passes")
         reg.counter_fn("repro_store_bytes_shipped_total",
                        lambda: stats.bytes_shipped,
                        help="host->device bytes actually shipped")
@@ -507,8 +517,8 @@ class DecoupledEngine:
                     with tr.span("calibrate", cat="calib"):
                         run_instrumented(self.program, self.params, db,
                                          self.impl, self._calib)
-                except Exception:    # calibration must never break
-                    pass             # serving
+                except Exception as e:
+                    self._exploration_failed("calibration", e)
         if plan is not None and not self._density_seeded \
                 and plan.n_edges is not None:
             # first measured batch density replaces the degree-based
@@ -541,6 +551,17 @@ class DecoupledEngine:
         return out
 
     # -- per-batch adaptive dispatch ----------------------------------------
+    def _exploration_failed(self, what: str, err: Exception) -> None:
+        """A discarded-output pass (calibration, warmup exploration,
+        block autotune) raised. Serving is unaffected — the jitted
+        program still answers the batch — but the failure is counted and
+        warned: a tuner that fails in silence never tunes."""
+        # run_device is the only caller: one device thread per engine
+        self.exploration_errors += 1
+        warnings.warn(f"{what} pass failed on {self.cfg.display}: "
+                      f"{type(err).__name__}: {err}", RuntimeWarning,
+                      stacklevel=2)
+
     def _count_dispatch(self, assignment: Dict[str, str],
                         sources: Dict[str, str]) -> None:
         """Per-mux-op dispatch counters:
@@ -601,8 +622,8 @@ class DecoupledEngine:
                 if pol.autotune_blocks and self.impl == "pallas":
                     run_block_autotune(self.program, self.params, db,
                                        pol.table)
-            except Exception:        # exploration must never break
-                pass                 # serving
+            except Exception as e:
+                self._exploration_failed("exploration", e)
         self._count_dispatch(dec.assignment, dec.site_sources)
         tr = self.tracer
         if tr is not None and tr.current() is not None:
@@ -616,6 +637,15 @@ class DecoupledEngine:
             variant_key(dec.assignment, dec.blocks),
             lambda: self._build_variant(dec.assignment, dec.blocks))
         return fn(self.params, db)
+
+    def programs(self) -> Dict[str, object]:
+        """The jitted device programs this deployment serves from: the
+        static program, or with dispatch on, every live variant (keyed
+        by its mode vector and block overrides). Each takes
+        ``(self.params, device_batch)``."""
+        if self._variants is None:
+            return {"static": self._infer}
+        return {repr(k): fn for k, fn in self._variants.items()}
 
     def dispatch_report(self) -> Optional[dict]:
         """Adaptive-dispatch state (the ``dispatch.*`` schema section):
@@ -632,11 +662,13 @@ class DecoupledEngine:
                     "mux_sites": sorted(self._static_assignment),
                     "decisions": self._forced_dispatch,
                     "sources": {"forced": self._forced_dispatch},
-                    "artifact": dconf.artifact}
+                    "artifact": dconf.artifact,
+                    "exploration_errors": self.exploration_errors}
         d = self.dispatch.report()
         d.update(enabled=True, variants=self._variants.stats(),
                  blocks=dict(self._last_blocks),
-                 artifact=dconf.artifact)
+                 artifact=dconf.artifact,
+                 exploration_errors=self.exploration_errors)
         return d
 
     def save_calibration(self, path: Optional[str] = None) -> str:
@@ -881,7 +913,6 @@ class DecoupledEngine:
             try:                     # best-effort: a failed save must
                 self.save_calibration()   # not block shutdown
             except Exception as e:
-                import warnings
                 warnings.warn(f"calibration save failed: {e}",
                               RuntimeWarning, stacklevel=2)
         if hasattr(self.graph, "unregister_listener"):

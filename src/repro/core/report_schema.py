@@ -65,12 +65,15 @@ Version history:
      and ``stages.batch_edges`` — the mean measured induced-subgraph
      edge count the Build stage reported (0.0 on pre-dispatch
      deployments and tier-only batches). Additive, like v2-v4.
+  6  ``dispatch.exploration_errors``: the count of failed calibration,
+     warmup-exploration and block-autotune passes (they used to be
+     dropped). Additive.
 """
 from __future__ import annotations
 
 from typing import Optional
 
-SCHEMA_VERSION = 5
+SCHEMA_VERSION = 6
 
 # documented key map (stable contract; bump SCHEMA_VERSION on change)
 SCHEMA = {
@@ -99,7 +102,8 @@ SCHEMA = {
                   "evaluations", "events"),
     "dispatch": ("enabled", "policy", "impl", "mux_sites", "decisions",
                  "sources", "warmup", "variants", "blocks",
-                 "table_cells", "table_passes", "artifact"),
+                 "table_cells", "table_passes", "artifact",
+                 "exploration_errors"),
 }
 
 

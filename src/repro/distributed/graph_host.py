@@ -19,7 +19,8 @@ Run standalone:
 
 prints ``GRAPH_HOST_LISTENING <host> <port>`` once ready (parents parse
 this to discover an ephemeral port) and serves until a ``shutdown`` RPC
-or SIGTERM.
+or SIGTERM. The process runs on the CPU platform only, so it can start
+beside a device host that holds the accelerator.
 """
 from __future__ import annotations
 
@@ -294,6 +295,13 @@ class GraphHostService:
 
 def main(argv=None) -> int:
     import argparse
+
+    import jax
+
+    # a graph host does host-only work (PPR push, induced subgraphs):
+    # pin it to the CPU before any backend starts, so it never claims
+    # an accelerator that its parent device host holds
+    jax.config.update("jax_platforms", "cpu")
 
     from repro.distributed.rpc import GraphHostServer
     from repro.graphs.synthetic import get_graph

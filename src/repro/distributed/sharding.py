@@ -26,14 +26,21 @@ BLOCK_KEYS = ("blocks", "dense_blocks", "enc_blocks")
 def shard_devices(num_shards: int) -> list:
     """Device list backing ``num_shards`` logical feature-store shards.
 
-    One device per shard when the host has enough; otherwise shards are
-    simulated — every table lands on the default device but keeps its own
-    budget/placement accounting (the store's ``simulated`` flag reports
-    which regime is active). The same helper keeps the store and any
-    future mesh-based layout agreeing on device order."""
+    One device per shard when the host has enough. On the CPU backend,
+    fewer devices means simulated shards — every table lands on the
+    default device but keeps its own budget/placement accounting (the
+    store's ``simulated`` flag reports which regime is active). On an
+    accelerator a shortfall raises instead: stacking shards on one chip
+    would silently drop the per-device capacity the shards exist for.
+    The same helper keeps the store and any future mesh-based layout
+    agreeing on device order."""
     devs = jax.devices()
     if len(devs) >= num_shards:
         return list(devs[:num_shards])
+    if devs[0].platform != "cpu":
+        raise ValueError(
+            f"{num_shards} feature-store shards need {num_shards} "
+            f"{devs[0].platform} devices; this host has {len(devs)}")
     return [devs[0]] * num_shards
 
 

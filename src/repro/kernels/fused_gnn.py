@@ -36,13 +36,14 @@ from jax.experimental import pallas as pl
 ACTS = {"none": lambda x: x, "relu": jax.nn.relu, "elu": jax.nn.elu}
 
 # block_f autotune grid (obs.calib.run_block_autotune): candidate output-
-# feature block widths, all 128-lane multiples except the 64 half-tile
-# for narrow heads. bf partitions Fout COLUMNS only — every candidate
+# feature block widths. Each is a multiple of the 128-lane tile, because
+# Mosaic refuses a lane block that is not (a 64 half-tile compiles only
+# in interpret mode). bf partitions Fout COLUMNS only — every candidate
 # computes each output column from the identical full-[Fin]/[N] reduction,
 # so tuning block_f never changes numerics, only VMEM footprint vs grid
 # parallelism. Candidates that don't divide Fout are skipped by the tuner
 # (the kernel asserts Fout % bf == 0).
-BLOCK_F_CANDIDATES = (64, 128, 256, 512)
+BLOCK_F_CANDIDATES = (128, 256, 512)
 
 
 def _kernel(a_ref, h_ref, wn_ref, ws_ref, b_ref, m_ref, o_ref, *,
@@ -57,7 +58,7 @@ def _kernel(a_ref, h_ref, wn_ref, ws_ref, b_ref, m_ref, o_ref, *,
     if use_self:
         acc += jnp.dot(h, ws_ref[...], preferred_element_type=jnp.float32)
     acc += b_ref[0].astype(jnp.float32)
-    out = ACTS[act](acc) * m_ref[0][:, None].astype(jnp.float32)
+    out = ACTS[act](acc) * m_ref[0, 0][:, None].astype(jnp.float32)
     o_ref[0] = out.astype(o_ref.dtype)
 
 
@@ -66,7 +67,11 @@ def fused_gnn_layer(adj, h, w_neigh, w_self=None, b=None, mask=None, *,
                     act: str = "relu", block_f: int = 256,
                     interpret: bool = False):
     """adj [C,N,N]; h [C,N,Fin]; w_neigh [Fin,Fout] (or None); w_self
-    [Fin,Fout] or None; b [Fout]; mask [C,N]. Returns [C,N,Fout]."""
+    [Fin,Fout] or None; b [Fout]; mask [C,N]. Returns [C,N,Fout].
+
+    The mask travels as [C,1,N] so its blocks are (1,1,N): a block's
+    last two dims must be (8,128)-aligned or whole for Mosaic, and a
+    (1,N) block of [C,N] is neither."""
     C, N, Fin = h.shape
     use_agg = w_neigh is not None
     use_self = w_self is not None
@@ -92,9 +97,9 @@ def fused_gnn_layer(adj, h, w_neigh, w_self=None, b=None, mask=None, *,
             pl.BlockSpec((Fin, bf), lambda c, j: (0, j)),          # w_neigh
             pl.BlockSpec((Fin, bf), lambda c, j: (0, j)),          # w_self
             pl.BlockSpec((1, bf), lambda c, j: (0, j)),            # b
-            pl.BlockSpec((1, N), lambda c, j: (c, 0)),             # mask
+            pl.BlockSpec((1, 1, N), lambda c, j: (c, 0, 0)),       # mask
         ],
         out_specs=pl.BlockSpec((1, N, bf), lambda c, j: (c, 0, j)),
         out_shape=jax.ShapeDtypeStruct((C, N, Fout), h.dtype),
         interpret=interpret,
-    )(adj, h, wn, ws, b.reshape(1, Fout), mask)
+    )(adj, h, wn, ws, b.reshape(1, Fout), mask.reshape(C, 1, N))

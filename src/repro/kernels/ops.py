@@ -1,8 +1,10 @@
 """Jit'd kernel entry points with automatic backend dispatch.
 
-On TPU the Pallas kernels run compiled; on CPU (this container) they run in
+On TPU the Pallas kernels run compiled (Mosaic); on CPU they run in
 ``interpret=True`` mode for correctness, and callers that want production
-CPU speed use the XLA reference path instead (``impl='xla'``). The engine's
+CPU speed use the XLA reference path instead (``impl='xla'``). Any other
+backend raises: a Pallas TPU kernel has no compiled form there, and
+interpreting it silently would hide which device serves. The engine's
 ACK dispatcher (core.ack) selects between dense/sg the way the paper's mode
 mux does.
 """
@@ -20,7 +22,14 @@ from repro.kernels.scatter_gather import \
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas ACK kernels compile for TPU and interpret on CPU only; "
+        f"the default backend is {backend!r}. Use impl='xla' there.")
 
 
 def fused_gnn_layer(*args, impl: str = "pallas", **kw):

@@ -39,15 +39,15 @@ BLOCK_E_CANDIDATES = (128, 256, 512)
 def _kernel(src_ref, dst_ref, w_ref, h_ref, o_ref, acc_ref):
     e_blk = pl.program_id(1)
     n = h_ref.shape[1]
-    eb = src_ref.shape[1]
+    eb = src_ref.shape[2]
 
     @pl.when(e_blk == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    src = src_ref[0]                                  # [EB] int32
-    dst = dst_ref[0]
-    w = w_ref[0]                                      # [EB] f32
+    src = src_ref[0, 0]                               # [EB] int32
+    dst = dst_ref[0, 0]
+    w = w_ref[0, 0]                                   # [EB] f32
     iota_n = jax.lax.broadcasted_iota(jnp.int32, (eb, n), 1)
     onehot_src = (iota_n == src[:, None]).astype(jnp.float32)   # [EB,N]
     onehot_dst = (iota_n == dst[:, None]).astype(jnp.float32)   # [EB,N]
@@ -71,6 +71,10 @@ def scatter_gather_aggregate(src, dst, w, h, *, block_e: int = 256,
     src/dst [C,E] int32 (padding edges must carry w==0 and any valid index);
     w [C,E] float; h [C,N,F]. Returns out [C,N,F] with
     out[c,i] = sum_e (dst[c,e]==i) * w[c,e] * h[c, src[c,e]].
+
+    The edge arrays travel as [C,1,E] so their blocks are (1,1,EB): a
+    block's last two dims must be (8,128)-aligned or whole for Mosaic,
+    and a (1,EB) block of [C,E] is neither.
     """
     C, E = src.shape
     _, N, F = h.shape
@@ -87,13 +91,13 @@ def scatter_gather_aggregate(src, dst, w, h, *, block_e: int = 256,
         _kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, eb), lambda c, e: (c, e)),        # src
-            pl.BlockSpec((1, eb), lambda c, e: (c, e)),        # dst
-            pl.BlockSpec((1, eb), lambda c, e: (c, e)),        # w
+            pl.BlockSpec((1, 1, eb), lambda c, e: (c, 0, e)),  # src
+            pl.BlockSpec((1, 1, eb), lambda c, e: (c, 0, e)),  # dst
+            pl.BlockSpec((1, 1, eb), lambda c, e: (c, 0, e)),  # w
             pl.BlockSpec((1, N, F), lambda c, e: (c, 0, 0)),   # h
         ],
         out_specs=pl.BlockSpec((1, N, F), lambda c, e: (c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((C, N, F), h.dtype),
         scratch_shapes=[pltpu.VMEM((N, F), jnp.float32)],
         interpret=interpret,
-    )(src, dst, w, h)
+    )(*(a.reshape(C, 1, E) for a in (src, dst, w)), h)

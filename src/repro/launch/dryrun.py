@@ -50,8 +50,6 @@ def run_cell(fn, args, in_sh, out_sh, mesh, n_devices: int,
         t_compile = time.time() - t0
         ma = compiled.memory_analysis()
         ca = compiled.cost_analysis() or {}
-        if isinstance(ca, (list, tuple)):   # older jax: one dict per device
-            ca = ca[0] if ca else {}
         hlo = analyze(compiled.as_text(), n_devices=n_devices)
     return {
         "ok": True,

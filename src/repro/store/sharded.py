@@ -432,6 +432,9 @@ class ShardedFeatureStore:
                 "num_shards": self.num_shards,
                 "placement": self.placement,
                 "simulated": self.simulated,
+                # where each shard table actually lives (device ids)
+                "shard_devices": [int(next(iter(t.devices())).id)
+                                  for t in pl.tables],
                 "resident_rows": sum(per_rows),
                 "resident_fraction": round(self.resident_fraction, 4),
                 "device_bytes": sum(int(t.nbytes) for t in pl.tables),

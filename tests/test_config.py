@@ -192,3 +192,40 @@ class TestReportSchema:
         assert "policy" in m["store"] and "features" in m["store"]
         assert rep["aggregate"]["latency"]["n"] == 4
         srv.engine_for("gcn").close()
+
+
+class TestCompileCache:
+    """Entry points place JAX's persistent compile cache through one
+    helper; the library itself never turns it on."""
+
+    @pytest.fixture
+    def updates(self, monkeypatch):
+        import jax
+        calls = {}
+        monkeypatch.setattr(jax.config, "update",
+                            lambda k, v: calls.__setitem__(k, v))
+        return calls
+
+    def test_env_dir_is_used_and_no_other_set(self, updates, monkeypatch,
+                                              tmp_path):
+        from repro.compile_cache import enable_compile_cache
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+        assert enable_compile_cache(str(tmp_path)) == str(tmp_path / "c")
+        assert "jax_compilation_cache_dir" not in updates
+
+    def test_fallback_is_fixed_path_in_checkout(self, updates, monkeypatch,
+                                               tmp_path):
+        from repro.compile_cache import enable_compile_cache
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        path = enable_compile_cache(str(tmp_path))
+        assert path == str(tmp_path / ".jax_cache")
+        assert updates["jax_compilation_cache_dir"] == path
+        assert enable_compile_cache(str(tmp_path)) == path    # stable
+
+    def test_library_import_leaves_cache_alone(self):
+        import os
+
+        import jax
+        import repro.serve.gnn_server  # noqa: F401
+        assert jax.config.jax_compilation_cache_dir == \
+            os.environ.get("JAX_COMPILATION_CACHE_DIR")

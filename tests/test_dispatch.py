@@ -254,18 +254,18 @@ class TestMeasuredDispatch:
 class TestBlockAutotune:
     def test_best_block_requires_full_grid(self):
         t = CalibrationTable()
-        cands = (64, 128, 256)
+        cands = (128, 256, 512)
         assert best_block(t, "fused_gnn", "bf=", cands, 7) is None
         # the tuner records every legal candidate in one pass per
         # bucket, so per-bucket legality == "has a cell at this bucket";
         # cells at OTHER buckets do not leak in
-        t.record("fused_gnn", "pallas/bf=64", 7, 2e-3)
-        t.record("fused_gnn", "pallas/bf=128", 7, 1e-3)
-        t.record("fused_gnn", "pallas/bf=256", 8, 9e-4)
-        assert best_block(t, "fused_gnn", "bf=", cands, 7) == 128
-        assert best_block(t, "fused_gnn", "bf=", cands, 8) == 256
-        t.record("fused_gnn", "pallas/bf=256", 7, 5e-4)
+        t.record("fused_gnn", "pallas/bf=128", 7, 2e-3)
+        t.record("fused_gnn", "pallas/bf=256", 7, 1e-3)
+        t.record("fused_gnn", "pallas/bf=512", 8, 9e-4)
         assert best_block(t, "fused_gnn", "bf=", cands, 7) == 256
+        assert best_block(t, "fused_gnn", "bf=", cands, 8) == 512
+        t.record("fused_gnn", "pallas/bf=512", 7, 5e-4)
+        assert best_block(t, "fused_gnn", "bf=", cands, 7) == 512
 
     def test_autotune_records_cells_and_policy_consumes(self):
         """run_block_autotune populates (kernel, pallas/b*=) cells for
@@ -278,6 +278,9 @@ class TestBlockAutotune:
         from repro.kernels.fused_gnn import BLOCK_F_CANDIDATES
         from repro.kernels.scatter_gather import BLOCK_E_CANDIDATES
         from repro.obs.calib import run_block_autotune, size_bucket
+        # the grid holds only blocks Mosaic accepts: whole 128-lane tiles
+        assert all(b % 128 == 0 for b in BLOCK_F_CANDIDATES)
+        assert all(b % 128 == 0 for b in BLOCK_E_CANDIDATES)
         g = get_graph("flickr", scale=0.005, seed=1)
         cfg = make_cfg(g)
         params = init_gnn(cfg, jax.random.PRNGKey(0))
@@ -302,8 +305,34 @@ class TestBlockAutotune:
         pol = DispatchPolicy(prog, "pallas", table, n=N,
                              f_in=cfg.f_in, f_hidden=cfg.f_hidden)
         blocks = pol._blocks(bucket)
-        assert blocks.get("block_f") in legal_bf
+        assert legal_bf and blocks.get("block_f") in legal_bf
         assert blocks.get("block_e") in BLOCK_E_CANDIDATES
+
+    def test_exploration_failures_are_counted_not_swallowed(
+            self, graph, monkeypatch):
+        """A failing autotune pass leaves serving intact, but every
+        failure is counted in dispatch_report(), warned, and exported as
+        repro_exploration_errors_total."""
+        import repro.obs.calib as calib
+        from repro.obs.metrics import TelemetryConfig
+
+        def broken(*a, **k):
+            raise RuntimeError("block refused")
+
+        monkeypatch.setattr(calib, "run_block_autotune", broken)
+        cfg = make_cfg(graph)
+        with pytest.warns(RuntimeWarning, match="block refused"):
+            with DecoupledEngine(graph, cfg, config=ServingConfig(
+                    batch_size=2, mode="auto", impl="pallas",
+                    telemetry=TelemetryConfig(),
+                    dispatch=DispatchConfig(warmup_passes=1))) as eng:
+                out = eng.infer(np.arange(8)).embeddings
+                rep = eng.dispatch_report()
+                text = eng.metrics_text(cluster=False)
+        assert np.isfinite(out).all() and out.shape[0] == 8
+        # one autotune pass per warmup batch: 2 slots (1 pass per side)
+        assert rep["exploration_errors"] == rep["sources"]["warmup"] == 2
+        assert "repro_exploration_errors_total" in text
 
 
 # ---------------------------------------------------------------------------

@@ -140,6 +140,24 @@ class TestGATAttention:
         np.testing.assert_allclose(np.asarray(got), 1.0, rtol=1e-5)
 
 
+class TestBackendDispatch:
+    @pytest.mark.parametrize("backend,interpret",
+                             [("tpu", False), ("cpu", True)])
+    def test_compiles_on_tpu_interprets_on_cpu(self, monkeypatch, backend,
+                                               interpret):
+        from repro.kernels import ops
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        assert ops._interpret() is interpret
+
+    def test_other_backends_refuse(self, monkeypatch):
+        """No silent interpret fallback: a GPU (or any other) backend
+        must pick impl='xla' explicitly."""
+        from repro.kernels import ops
+        monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+        with pytest.raises(RuntimeError, match="impl='xla'"):
+            ops._interpret()
+
+
 class TestFlashAttention:
     @pytest.mark.parametrize("causal", [True, False])
     @pytest.mark.parametrize("b,h,sq,sk,d,bq,bk", [

@@ -241,6 +241,24 @@ class TestRemoteBitwise:
             proc.kill()
             proc.wait(timeout=10)
 
+    def test_graph_host_process_pins_cpu(self):
+        """A graph host never claims the accelerator its parent device
+        host holds: main() pins JAX to the CPU platform before any
+        backend starts, whatever JAX_PLATFORMS the parent passed on."""
+        env = _subproc_env()
+        env.pop("JAX_PLATFORMS", None)
+        code = ("import jax\n"
+                "from repro.distributed import graph_host\n"
+                "try:\n"
+                "    graph_host.main(['--help'])\n"
+                "except SystemExit:\n"
+                "    pass\n"
+                "print(jax.config.jax_platforms)\n")
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=120)
+        assert out.returncode == 0, out.stderr
+        assert out.stdout.strip().splitlines()[-1] == "cpu"
+
 
 class TestFailureIsolation:
     def test_kill_graph_host_errors_only_inflight_tickets(self, graph):

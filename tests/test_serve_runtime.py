@@ -162,6 +162,16 @@ class TestSharedPlan:
         eng.close()
 
 
+    def test_spec_names_its_chip(self):
+        import types
+        spec = TPUSpec()
+        spec.check_device(types.SimpleNamespace(
+            device_kind=spec.device_kind, platform="tpu"))
+        with pytest.raises(RuntimeError, match="TPU v4"):
+            spec.check_device(types.SimpleNamespace(
+                device_kind="TPU v4", platform="tpu"))
+
+
 class TestMultiModelServer:
     def test_two_kinds_concurrently_match_standalone(self, graph):
         engines = {k: make_engine(graph, k) for k in ("gcn", "sage")}

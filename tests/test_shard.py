@@ -64,6 +64,28 @@ class TestPolicyValidation:
         assert d["shard_budget_bytes"] == [1, 2, 3, 4]
 
 
+class TestShardDevices:
+    def test_cpu_simulates_missing_devices(self):
+        import jax
+        from repro.distributed.sharding import shard_devices
+        n = len(jax.devices()) + 2
+        devs = shard_devices(n)
+        assert len(devs) == n and len(set(devs)) < n
+
+    def test_accelerator_shortfall_raises(self, monkeypatch):
+        """On a chip, 4 shards on 1 device would silently give up the
+        per-device capacity the shards exist for."""
+        import types
+
+        import jax
+        from repro.distributed.sharding import shard_devices
+        chip = types.SimpleNamespace(platform="tpu")
+        monkeypatch.setattr(jax, "devices", lambda: [chip])
+        with pytest.raises(ValueError, match="4 feature-store shards"):
+            shard_devices(4)
+        assert shard_devices(1) == [chip]
+
+
 class TestCrossShardGather:
     @pytest.mark.parametrize("placement", ["hash", "range"])
     @pytest.mark.parametrize("num_shards", [2, 4])
