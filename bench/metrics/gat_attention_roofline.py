@@ -1,0 +1,17 @@
+"""The GAT attention kernel (kernels/gat_attention.py): least time on the
+chip for its traced calls over their measured device time."""
+from bench import tracing
+
+LAYER = "kernels"
+UNIT = "%"
+SOURCE = "device_trace"
+MOVES = "latency_p50_ms"
+BETTER = "higher"
+KERNEL = "gat_attention"
+
+
+def read(run):
+    if run.trace is None:
+        return None
+    return tracing.kernel_roofline(run.trace, KERNEL, run.cell.model,
+                                   run.peaks)
