@@ -93,12 +93,8 @@ class DecoupledEngine:
         if config.telemetry is not None:
             from repro.obs.metrics import Telemetry
             self.telemetry = Telemetry(config.telemetry, host="client")
-            self._h_gather = self.telemetry.whist(
-                "repro_store_gather_seconds",
-                help="device-side feature gather wall time")
         else:
             self.telemetry = None
-            self._h_gather = None
         self.batch_size = config.batch_size
         self.num_threads = config.num_threads
         self.impl = config.impl
@@ -490,18 +486,16 @@ class DecoupledEngine:
         tr = self.tracer
         if all(k in db for k in src.payload_keys):
             payload = {k: db.pop(k) for k in src.payload_keys}
-            tg = time.perf_counter() if self._h_gather is not None \
-                else 0.0
             if tr is None:
                 feats = src.device_feats(payload)
             else:
                 # child of the scheduler's "device" span (thread-local
-                # parent); no-ops when this batch is untraced
+                # parent); no-ops when this batch is untraced. It times
+                # the host's asynchronous dispatch of the gather, not
+                # the gather on the device
                 with tr.span("store.gather", cat="store",
                              store=src.name):
                     feats = src.device_feats(payload)
-            if self._h_gather is not None:
-                self._h_gather.record(time.perf_counter() - tg)
         else:       # externally built dense batch (e.g. device_batch())
             feats = db["feats"]
         db["feats"] = self._pad_feature_dim(feats)
@@ -707,9 +701,11 @@ class DecoupledEngine:
     def submit_chunk(self, targets, on_done=None) -> StreamTicket:
         """Streaming entry: enqueue ONE micro-batch (≤ C targets, tail is
         padded) on the persistent pipeline; returns a StreamTicket whose
-        result is the [C, f] embedding block."""
+        result is the [C, f] embedding block. The ticket's hand-off
+        ledger starts at this call."""
+        t_call = time.perf_counter()
         return self.scheduler.submit(self.pad_targets(np.asarray(targets)),
-                                     on_done=on_done)
+                                     on_done=on_done, t_call=t_call)
 
     def infer(self, targets, overlap: bool = True) -> InferenceResult:
         """Mini-batch inference for arbitrary #targets (chunks of C)."""

@@ -850,17 +850,25 @@ def compile_steps(seq: Sequence[AckOp], impl: str,
 
 
 def _compile_section(seq: Sequence[AckOp], impl: str,
-                     blocks: BlockSpec = None):
-    """Unlabeled section lowering for the jitted execution path."""
-    steps = [step for _, step in compile_steps(seq, impl, blocks)]
+                     blocks: BlockSpec = None, scope: str = "ack"):
+    """Section lowering for the jitted execution path. Step k runs under
+    ``jax.named_scope("<scope>.<k>.<op kinds>")`` (e.g.
+    ``ack.l0.0.aggregate_residual_transform``), so the device ops it
+    lowers to carry their ACK op in a profile's op metadata; the kernels'
+    own names are unchanged."""
+    steps = [(f"{scope}.{k}." + "_".join(type(o).__name__.lower()
+                                          for o in ops), step)
+             for k, (ops, step) in enumerate(compile_steps(seq, impl,
+                                                            blocks))]
 
     def apply(p, h, batch, h0=None):
         # "h0" is the propagation ENTRY state: the layer input for
         # layer0, the post-layer0 prediction (constant across the inner
         # scan) for inner layers — APPNP's teleport anchor
         regs = {"h": h, "h_in": h, "h0": h if h0 is None else h0}
-        for s in steps:
-            s(p, regs, batch)
+        for name, s in steps:
+            with jax.named_scope(name):
+                s(p, regs, batch)
         return regs["h"]
     return apply
 
@@ -875,23 +883,25 @@ def execute(prog: AckProgram, params, batch, impl: str = "xla",
     if not prog.specialized:
         raise ValueError(
             "program has unspecialized mux ops — call specialize() first")
-    apply0 = _compile_section(prog.layer0, impl, blocks)
+    apply0 = _compile_section(prog.layer0, impl, blocks, "ack.l0")
     h = apply0(params["layer0"], batch["feats"], batch)
     if prog.n_layers > 1:
-        apply_i = _compile_section(prog.inner, impl, blocks)
+        # one scan body serves layers 1 .. L-1
+        apply_i = _compile_section(prog.inner, impl, blocks, "ack.inner")
         h0 = h                      # scan-entry prediction, teleport anchor
 
         def body(hh, lp):
             return apply_i(lp, hh, batch, h0=h0), None
         h, _ = jax.lax.scan(body, h, params["layers"])
     emb = h
-    for op in prog.tail:
-        if isinstance(op, Readout):
-            emb = readout(h, batch["mask"], op.kind)
-        elif isinstance(op, Classify):
-            emb = emb @ params[op.w] + params[op.b]
-        else:
-            raise TypeError(f"op {op!r} is not a tail op")
+    with jax.named_scope("ack.tail"):
+        for op in prog.tail:
+            if isinstance(op, Readout):
+                emb = readout(h, batch["mask"], op.kind)
+            elif isinstance(op, Classify):
+                emb = emb @ params[op.w] + params[op.b]
+            else:
+                raise TypeError(f"op {op!r} is not a tail op")
     return emb, h
 
 

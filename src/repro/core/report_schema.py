@@ -10,7 +10,8 @@ can detect drift:
               Eq. 2 terms) and, per served model, the request
               percentiles p50/p90/p99/mean/batch_mean/n
   stages.*    host BatchPlan pipeline: per-stage wall totals ("times",
-              the software Fig. 3 breakdown), achieved overlap
+              the software Fig. 3 breakdown), the hand-off ledger's
+              waits and device intervals ("waits"), achieved overlap
               fraction, batch count, Build-stage row-cache hit rate
   store.*     transfer + cache accounting (paper t_load / t_pre):
               bytes_shipped / bytes_dense / transfer_ratio /
@@ -68,18 +69,21 @@ Version history:
   6  ``dispatch.exploration_errors``: the count of failed calibration,
      warmup-exploration and block-autotune passes (they used to be
      dropped). Additive.
+  7  ``stages.waits``: the scheduler's hand-off ledger (``queue.*`` waits
+     and ``device.*`` intervals, ``SchedulerStats.wait_times``); ``times``
+     stays service time only. Additive.
 """
 from __future__ import annotations
 
 from typing import Optional
 
-SCHEMA_VERSION = 6
+SCHEMA_VERSION = 7
 
 # documented key map (stable contract; bump SCHEMA_VERSION on change)
 SCHEMA = {
     "latency": ("t_wall", "t_host", "t_device", "t_init",
                 "p50", "p90", "p99", "mean", "batch_mean", "n", "hist"),
-    "stages": ("times", "overlap", "batches", "build_hit_rate",
+    "stages": ("times", "waits", "overlap", "batches", "build_hit_rate",
                "batch_edges"),
     "store": ("bytes_shipped", "bytes_dense", "transfer_ratio",
               "cache_hit_rate", "dedup_ratio", "policy", "features",
@@ -109,7 +113,9 @@ SCHEMA = {
 
 def stages_section(stats) -> dict:
     return {"times": {k: round(v, 6)
-                      for k, v in stats.stage_times.items()},
+                      for k, v in stats.service_times.items()},
+            "waits": {k: round(v, 6)
+                      for k, v in stats.wait_times.items()},
             "overlap": round(stats.overlap_fraction, 3),
             "batches": stats.n_batches,
             "build_hit_rate": round(stats.build_hit_rate, 4),

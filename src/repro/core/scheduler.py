@@ -26,6 +26,20 @@ batches" property at the software layer.
 ``SchedulerStats`` reports the paper's §5.4 quantities: t_initialization
 (first-batch host latency, the un-hideable prologue), per-stage sums, and
 the achieved overlap fraction.
+
+Every ticket also keeps a hand-off ledger: ``StreamTicket.mark`` stamps
+``time.perf_counter()`` at each hand-off (submit, admission, each stage's
+start and end, the dispatcher's pick-up, the program's launch, the drain,
+``block_until_ready``, the end of the completion callbacks), so the
+ledger's intervals and the stage service times add up exactly to the
+ticket's time from submit to completion. Completed tickets fold the
+ledger into ``SchedulerStats.stage_times`` under namespaced keys
+(``queue.*`` for waits, ``device.*`` for the device station's own
+intervals) beside the stage service times; ``service_times`` and
+``wait_times`` split the two. Each station also runs under a
+``jax.profiler.TraceAnnotation`` named ``repro.<station>`` (with the
+batch's ``seq`` and the wait before it), so a profile shows the stations
+on the device trace's clock.
 """
 from __future__ import annotations
 
@@ -38,6 +52,7 @@ from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import jax
+from jax.profiler import TraceAnnotation
 
 from repro.core.report_schema import scheduler_summary
 
@@ -45,6 +60,8 @@ from repro.core.report_schema import scheduler_summary
 # are kept verbatim (recent forensics); older ones roll off, so stats
 # memory is O(1) in batch count (cumulative totals stay exact)
 RECENT_TIMES = 512
+# profiler annotations of the stations (``repro.select``, ``repro.device``)
+ANNOTATION_PREFIX = "repro."
 
 
 @dataclass
@@ -60,7 +77,9 @@ class SchedulerStats:
         default_factory=lambda: deque(maxlen=RECENT_TIMES))
     # per-stage host wall time totals (staged pipelines only; the
     # one-stage host_fn spelling accumulates under "host") — the paper's
-    # Fig. 3 breakdown of the host budget
+    # Fig. 3 breakdown of the host budget — and, under namespaced keys
+    # ("queue.<stage>", "device.ready", ...), the completed tickets'
+    # hand-off ledgers (see ``service_times`` / ``wait_times``)
     stage_times: Dict[str, float] = field(default_factory=dict)
     # host->device transfer accounting (the paper's t_load, Eq. 2): what
     # actually crossed the link vs. what the dense baseline would ship,
@@ -105,6 +124,25 @@ class SchedulerStats:
         if lo <= 0 or serial <= self.t_wall:
             return 0.0 if serial <= self.t_wall else 1.0
         return min(1.0, (serial - self.t_wall) / lo)
+
+    @property
+    def service_times(self) -> Dict[str, float]:
+        """Stage service times: the plain stage names of ``stage_times``."""
+        return {k: v for k, v in list(self.stage_times.items())
+                if "." not in k}
+
+    @property
+    def wait_times(self) -> Dict[str, float]:
+        """The hand-off ledger: ``queue.admit`` (blocked on the in-flight
+        bound), ``queue.<stage>`` (handed to a stage until its station
+        starts), ``queue.dispatch`` (last stage done until the dispatcher
+        picks the batch up), ``device.launch`` (the device function's
+        call), ``queue.drain`` (launched until the drain starts),
+        ``device.ready`` (``block_until_ready``), ``device.reply``
+        (completion bookkeeping and callbacks), and ``queue.lane`` (per
+        answered request, from the server's lane)."""
+        return {k: v for k, v in list(self.stage_times.items())
+                if "." in k}
 
     @property
     def cache_hit_rate(self) -> float:
@@ -166,18 +204,27 @@ class StreamTicket:
     (``stage_times`` the named host-stage split); ``on_done(ticket)`` (if
     given) fires on the dispatcher thread — keep it light (recording
     latencies, handing results to waiters).
+
+    ``ledger`` holds the intervals between hand-offs (the keys of
+    ``SchedulerStats.wait_times``); ``t_call`` is the submit call and
+    ``t_done`` the end of the completion callbacks, and ``ledger`` plus
+    ``stage_times`` add up to ``t_done - t_call``.
     """
 
-    __slots__ = ("item", "seq", "on_done", "t_submit", "t_host", "t_device",
+    __slots__ = ("item", "seq", "on_done", "t_call", "t_host", "t_device",
                  "stage_times", "output", "error", "trace", "_event",
-                 "_host_future")
+                 "_host_future", "t_mark", "t_done", "ledger")
 
     def __init__(self, item: Any, seq: int,
-                 on_done: Optional[Callable] = None):
+                 on_done: Optional[Callable] = None,
+                 t_call: Optional[float] = None):
         self.item = item
         self.seq = seq
         self.on_done = on_done
-        self.t_submit = time.perf_counter()
+        self.t_call = time.perf_counter() if t_call is None else t_call
+        self.t_mark = self.t_call        # the newest hand-off mark
+        self.t_done = 0.0
+        self.ledger: Dict[str, float] = {}
         self.t_host = 0.0
         self.t_device = 0.0
         self.stage_times: Dict[str, float] = {}
@@ -186,6 +233,18 @@ class StreamTicket:
         self.trace = None            # obs.TraceContext when sampled
         self._event = threading.Event()
         self._host_future = None
+
+    def mark(self, key: str, book: Optional[Dict[str, float]] = None
+             ) -> float:
+        """Stamp a hand-off: the time since the previous mark is added
+        under ``key`` to ``book`` (default the ledger; a stage's service
+        time goes to ``stage_times``). Returns that interval."""
+        t = time.perf_counter()
+        dt = t - self.t_mark
+        book = self.ledger if book is None else book
+        book[key] = book.get(key, 0.0) + dt
+        self.t_mark = t
+        return dt
 
     def done(self) -> bool:
         return self._event.is_set()
@@ -322,21 +381,27 @@ class PipelineScheduler:
                 self._complete(t)
 
     # -- host execution ------------------------------------------------------
-    def _traced(self, name: str, ticket: StreamTicket, fn, *args):
-        """Run one pipeline step, under a span when the ticket is traced
-        (the untraced path is a single attribute test + call)."""
-        tr = self.tracer
-        if tr is None or ticket.trace is None:
-            return fn(*args)
-        with tr.span(name, ctx=ticket.trace, seq=ticket.seq):
-            return fn(*args)
+    def _traced(self, name: str, ticket: StreamTicket, queued: float,
+                fn, *args):
+        """Run one station's work for ``ticket``: always under the
+        profiler annotation ``repro.<name>`` (about a microsecond while no
+        profile is taken), and under a Tracer span when the batch is
+        sampled. Both carry the batch's ``seq`` and ``queued_us``, the
+        ledger's wait before the station."""
+        queued_us = round(queued * 1e6, 1)
+        with TraceAnnotation(ANNOTATION_PREFIX + name, seq=ticket.seq,
+                             queued_us=queued_us):
+            tr = self.tracer
+            if tr is None or ticket.trace is None:
+                return fn(*args)
+            with tr.span(name, ctx=ticket.trace, seq=ticket.seq,
+                         queued_us=queued_us):
+                return fn(*args)
 
     def _timed_host(self, ticket: StreamTicket):
-        t = time.perf_counter()
-        hb = self._traced("host", ticket, self.host_fn, ticket.item)
-        dt = time.perf_counter() - t
-        ticket.stage_times["host"] = dt
-        return hb, dt
+        queued = ticket.mark("queue.host")
+        hb = self._traced("host", ticket, queued, self.host_fn, ticket.item)
+        return hb, ticket.mark("host", ticket.stage_times)
 
     def _host_serial(self, item, stage_times: Optional[Dict] = None):
         """Run the full host side inline (run()'s no-overlap path)."""
@@ -358,17 +423,14 @@ class PipelineScheduler:
 
     def _stage_step(self, ticket: StreamTicket, i: int, value):
         st = self.stages[i]
-        t0 = time.perf_counter()
+        queued = ticket.mark("queue." + st.name)
         try:
-            out = self._traced(st.name, ticket, st.run, value)
+            out = self._traced(st.name, ticket, queued, st.run, value)
         except BaseException as e:             # noqa: BLE001
-            ticket.stage_times[st.name] = \
-                ticket.stage_times.get(st.name, 0.0) \
-                + time.perf_counter() - t0
+            ticket.mark(st.name, ticket.stage_times)
             ticket._host_future.set_exception(e)
             return
-        ticket.stage_times[st.name] = \
-            ticket.stage_times.get(st.name, 0.0) + time.perf_counter() - t0
+        ticket.mark(st.name, ticket.stage_times)
         if i + 1 < len(self.stages):
             try:
                 self._stage_pools[i + 1].submit(self._stage_step, ticket,
@@ -390,20 +452,24 @@ class PipelineScheduler:
                                         ticket.item)
 
     # -- streaming interface -------------------------------------------------
-    def submit(self, item, on_done: Optional[Callable] = None
-               ) -> StreamTicket:
-        """Enqueue one micro-batch; blocks when max_inflight is reached."""
+    def submit(self, item, on_done: Optional[Callable] = None,
+               t_call: Optional[float] = None) -> StreamTicket:
+        """Enqueue one micro-batch; blocks when max_inflight is reached.
+        ``t_call`` (default: now) is where the ticket's ledger starts."""
+        if t_call is None:
+            t_call = time.perf_counter()
         self.start()
         self._slots.acquire()
         if self._closed:             # close() ran while we were blocked
             self._slots.release()
             raise RuntimeError("scheduler is closed")
         with self._lock:
-            t = StreamTicket(item, self._seq, on_done)
+            t = StreamTicket(item, self._seq, on_done, t_call=t_call)
             self._seq += 1
             if self._inflight == 0:
                 self._active_since = time.perf_counter()
             self._inflight += 1
+        t.mark("queue.admit")
         if self.tracer is not None:
             t.trace = self.tracer.maybe_trace(seq=t.seq)
         try:
@@ -452,6 +518,14 @@ class PipelineScheduler:
                 for i, b in enumerate(shard_bytes):
                     s.shard_bytes[i] += int(b)
 
+    def note_waits(self, *, lane: float = 0.0):
+        """Accumulate waits measured outside the scheduler: ``lane`` is
+        the summed time a batch's answered requests spent in the server's
+        lane before the batch was submitted (``queue.lane``)."""
+        with self._lock:
+            st = self.stats.stage_times
+            st["queue.lane"] = st.get("queue.lane", 0.0) + float(lane)
+
     def note_rpc_metrics(self, *, calls: int = 0, bytes_out: int = 0,
                          bytes_in: int = 0, retries: int = 0,
                          timeouts: int = 0, errors: int = 0,
@@ -483,6 +557,23 @@ class PipelineScheduler:
         with self._lock:             # same lock as run()'s serial recorder
             self.stats.record(ticket.t_host, ticket.t_device)
             self.stats.merge_stage_times(ticket.stage_times)
+        self._traced("reply", ticket, 0.0, self._reply, ticket)
+        ticket.mark("device.reply")
+        ticket.t_done = ticket.t_mark
+        # in-flight accounting last, so flush() implies callbacks finished
+        # and the ledger is folded in
+        with self._idle:
+            if ticket.error is None:
+                self.stats.merge_stage_times(ticket.ledger)
+            self._inflight -= 1
+            if self._inflight == 0 and self._active_since is not None:
+                self.stats.t_wall += time.perf_counter() - self._active_since
+                self._active_since = None
+            self._idle.notify_all()
+        self._slots.release()
+
+    def _reply(self, ticket: StreamTicket):
+        """Completion bookkeeping and callbacks of one batch."""
         if ticket.trace is not None:
             # close the batch's span tree before waiters wake, so a
             # result() immediately followed by export sees the full tree
@@ -492,7 +583,7 @@ class PipelineScheduler:
                 t_device=round(ticket.t_device, 6))
         if self.telemetry is not None:
             self.telemetry.observe_batch(
-                time.perf_counter() - ticket.t_submit,
+                time.perf_counter() - ticket.t_call,
                 ticket.stage_times, error=ticket.error is not None)
         ticket._event.set()          # resolve BEFORE on_done: callbacks may
         if ticket.on_done is not None:           # call ticket.result()
@@ -505,14 +596,6 @@ class PipelineScheduler:
                 self.on_batch(ticket)            # never kills the pipeline
             except Exception:
                 pass
-        # in-flight accounting last, so flush() implies callbacks finished
-        with self._idle:
-            self._inflight -= 1
-            if self._inflight == 0 and self._active_since is not None:
-                self.stats.t_wall += time.perf_counter() - self._active_since
-                self._active_since = None
-            self._idle.notify_all()
-        self._slots.release()
 
     def _dispatch_loop(self):
         pending: Optional[StreamTicket] = None
@@ -533,36 +616,37 @@ class PipelineScheduler:
                 if pending is not None:
                     self._drain(pending)
                 break
-            td0 = time.perf_counter()
             try:
                 hb, t.t_host = t._host_future.result()
-                td0 = time.perf_counter()
+                queued = t.mark("queue.dispatch")
                 # "device" span = dispatch of the jitted program (async);
                 # the sync wait shows up as the "drain" span in _drain
-                t.output = self._traced("device", t, self.device_fn, hb)
+                t.output = self._traced("device", t, queued,
+                                        self.device_fn, hb)
+                t.t_device = t.mark("device.launch")
             except BaseException as e:             # noqa: BLE001
                 t.error = e
             if pending is not None:                # drain batch i-1 while
                 self._drain(pending)               # batch i computes
                 pending = None
-            t.t_device = time.perf_counter() - td0
             if t.error is not None:
                 self._complete(t)
             elif self._order_q.empty():
                 # nothing behind us: finish now for lowest tail latency
-                self._drain(t, extra_device_time=True)
+                self._drain(t)
             else:
                 pending = t
 
-    def _drain(self, ticket: StreamTicket, extra_device_time: bool = False):
-        t0 = time.perf_counter()
+    def _drain(self, ticket: StreamTicket):
+        queued = ticket.mark("queue.drain")
         try:
-            self._traced("drain", ticket, jax.block_until_ready,
+            self._traced("drain", ticket, queued, jax.block_until_ready,
                          ticket.output)
         except BaseException as e:                 # noqa: BLE001
             ticket.error = e
-        if extra_device_time:
-            ticket.t_device += time.perf_counter() - t0
+        # the batch's device time is its own launch and ready wait, not
+        # the drain of the batch before it
+        ticket.t_device += ticket.mark("device.ready")
         self._complete(ticket)
 
     # -- batch interface (offline inference) ---------------------------------

@@ -17,6 +17,13 @@ module records what actually happened to individual batches:
   report surfaces exact-from-buckets p50/p90/p99 without unbounded
   lists.
 
+The scheduler's stations also run, always, under
+``jax.profiler.TraceAnnotation("repro.<station>")`` with the batch's
+``seq`` and the wait before the station (``queued_us``, from the ticket's
+hand-off ledger); a traced batch's station spans carry the same two args.
+So a profile places the stations on the device trace's clock, and the
+export here shows the queueing between them.
+
 Tracing is **opt-in and zero-cost when off**: with
 ``ServingConfig(trace=None)`` (the default) no tracer object exists and
 every instrumentation site is a single ``is None`` test; traced and

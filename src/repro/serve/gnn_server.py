@@ -24,6 +24,7 @@ from dataclasses import dataclass, field
 from typing import Dict, List, Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.config import ServingConfig
 from repro.core.dse import DSEPlan, TPUSpec, explore, validate_models
@@ -146,16 +147,20 @@ class _ModelLane:
 
     def _batch_loop(self):
         while not self._stop.is_set():
-            reqs = self._collect_batch()
+            # profiler annotations: waiting for requests, and blocking on
+            # the scheduler's admission, each labelled as such
+            with TraceAnnotation("repro.lane.collect"):
+                reqs = self._collect_batch()
             if not reqs:
                 continue
             targets = np.array([r.target for r in reqs])
             t0 = time.perf_counter()
             # streams into the engine's ONE persistent pipeline; blocks
             # only when the scheduler's in-flight bound applies backpressure
-            self.engine.submit_chunk(
-                targets,
-                on_done=lambda tk, rs=reqs, ts=t0: self._on_done(rs, ts, tk))
+            with TraceAnnotation("repro.lane.submit"):
+                self.engine.submit_chunk(
+                    targets, on_done=lambda tk, rs=reqs, ts=t0:
+                    self._on_done(rs, ts, tk))
 
     def _on_done(self, reqs: List[Request], t0: float, ticket):
         t1 = time.perf_counter()
@@ -174,6 +179,10 @@ class _ModelLane:
             if self._h_request is not None:
                 self._h_request.record(r.latency)
         self.stats.record_batch(t1 - t0)
+        # queue.lane: each answered request's wait from its enqueue until
+        # its batch entered submit_chunk
+        self.engine.scheduler.note_waits(
+            lane=sum(t0 - r.t_enqueue for r in reqs))
 
     # -- lifecycle -----------------------------------------------------------
     def start(self):

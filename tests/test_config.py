@@ -161,8 +161,9 @@ class TestReportSchema:
             assert s["schema_version"] == SCHEMA_VERSION
             for key in ("t_wall", "t_host", "t_device", "t_init"):
                 assert key in s["latency"]
-            assert set(s["stages"]) == {"times", "overlap", "batches",
-                                        "build_hit_rate", "batch_edges"}
+            assert set(s["stages"]) == {"times", "waits", "overlap",
+                                        "batches", "build_hit_rate",
+                                        "batch_edges"}
             for key in ("bytes_shipped", "bytes_dense", "transfer_ratio",
                         "cache_hit_rate", "dedup_ratio"):
                 assert key in s["store"]

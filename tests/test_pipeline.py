@@ -108,7 +108,7 @@ class TestStagedEqualsMonolithic:
             mono.close()
             np.testing.assert_array_equal(
                 staged, np.concatenate([np.asarray(o) for o in outs]))
-            assert list(stats.stage_times) == ["host"]
+            assert list(stats.service_times) == ["host"]
 
     def test_stage_times_reported(self, graph):
         cfg = _cfg("gcn", graph)
@@ -465,11 +465,11 @@ class TestPipelinedScheduling:
         outs = [t.result() for t in t0]
         assert [int(np.asarray(o)) for o in outs] == [0, 1, 2]
         st = s.stats
-        assert set(st.stage_times) == {"a", "b"}
+        assert set(st.service_times) == {"a", "b"}
         # pipelined wall < serial sum of stage times (3 batches x 2
         # stages x 50ms serial = 300ms; pipelined ~200ms)
-        assert st.t_wall < 0.9 * (st.stage_times["a"]
-                                  + st.stage_times["b"])
+        assert st.t_wall < 0.9 * (st.service_times["a"]
+                                  + st.service_times["b"])
         s.close()
 
     def test_stage_error_isolated_to_ticket(self):
