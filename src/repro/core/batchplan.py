@@ -18,9 +18,9 @@ The artifact each stage produces/consumes is a ``BatchPlan``:
                                 adjacency/edge blocks), via the
                                 subgraph-row cache when configured —
                                 a hit skips construction entirely
-  Pack     rows               -> fixed-shape SubgraphBatch + the store
-                                strategy's device payload + transfer
-                                accounting
+  Pack     rows               -> the fixed-shape arrays the compiled
+                                program reads + the store strategy's
+                                device payload + transfer accounting
 
 ``DecoupledEngine`` instantiates the three stages and hands them to
 ``PipelineScheduler``; running them back-to-back on one thread is exactly
@@ -35,8 +35,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from repro.core.subgraph import (SubgraphBatch, SubgraphRows,
-                                 assemble_batch, build_subgraph_rows)
+from repro.core.subgraph import (SubgraphRows, build_subgraph_rows,
+                                 host_feats, stack_rows)
 from repro.store.nbr_cache import nbr_key
 
 
@@ -62,7 +62,6 @@ class BatchPlan:
     n_vertices: Optional[float] = None   # mean real vertices / subgraph
     n_edges: Optional[float] = None      # mean real edges / subgraph
     # Pack
-    sb: Optional[SubgraphBatch] = None
     device: Optional[Dict[str, np.ndarray]] = None
     # Tier (hybrid precompute routing; set by precompute.TierStage)
     tier_rows: Optional[np.ndarray] = None   # [C, f_out] (stale rows 0)
@@ -232,9 +231,17 @@ class BuildStage(PlanStage):
 
 
 class PackStage(PlanStage):
-    """Assemble the fixed-shape SubgraphBatch from the built rows, attach
-    the feature-store payload, and account the transfer (what this
-    strategy ships vs. what the dense baseline would)."""
+    """Build the batch's device dict straight from the built rows:
+    exactly the structure arrays the compiled program reads
+    (``engine.structure_keys``: ``mask``, the adjacencies of
+    ``adj_keys``, the edge arrays only when ``needs_edges``), the dense
+    feature block only when the store ships it from the host, and the
+    store's payload. No other array is allocated, and each run of one
+    row object is copied once (``stack_rows``). ``assemble_batch`` builds
+    the full SubgraphBatch instead, for offline use and training.
+
+    Accounts the batch: the bytes Pack built, the bytes it ships, and
+    what the dense-feature baseline would ship."""
 
     name = "pack"
 
@@ -249,13 +256,10 @@ class PackStage(PlanStage):
         eng = self.engine
         src = eng._fsource
         n = eng.cfg.receptive_field
-        sb = assemble_batch(eng.graph, plan.targets, plan.node_lists,
-                            plan.rows, n, eng.e_pad,
-                            build_feats=src.needs_host_feats)
-        plan.sb = sb
-        d = eng.device_batch(sb, include_feats=False)
-        payload, dedup = src.host_payload(
-            plan.node_lists, n, sb.feats if src.needs_host_feats else None)
+        d = stack_rows(plan.rows, eng.structure_keys, n, eng.e_pad)
+        feats = host_feats(eng.graph, plan.node_lists, n) \
+            if src.needs_host_feats else None
+        payload, dedup = src.host_payload(plan.node_lists, n, feats)
         if dedup is not None:
             eng.last_dedup_ratio = dedup
         # transfer accounting: what this strategy ships vs. what the dense
@@ -263,12 +267,16 @@ class PackStage(PlanStage):
         other = sum(int(a.nbytes) for a in d.values())
         shipped = other + sum(int(a.nbytes) for a in payload.values())
         dense = other + len(plan.node_lists) * n * eng.f_pad * 4
+        packed = shipped
+        if feats is not None and all(a is not feats
+                                     for a in payload.values()):
+            packed += feats.nbytes   # padded into a new payload array
         d.update(payload)
         # sharded store: per-shard share of this payload's bytes (pure
         # function of the payload — safe from concurrent stage threads)
         per_shard = getattr(src, "shard_metrics_for", None)
         eng.scheduler.note_host_metrics(
-            bytes_shipped=shipped, bytes_dense=dense,
+            bytes_shipped=shipped, bytes_dense=dense, bytes_packed=packed,
             cache_hits=plan.nbr_hits, cache_misses=plan.nbr_misses,
             build_hits=plan.build_hits, build_misses=plan.build_misses,
             dedup_ratio=dedup,
@@ -277,5 +285,6 @@ class PackStage(PlanStage):
         plan.device = d
         tr = eng.tracer
         if tr is not None:           # annotate this batch's pack span
-            tr.annotate(bytes_shipped=shipped, bytes_dense=dense)
+            tr.annotate(bytes_shipped=shipped, bytes_dense=dense,
+                        bytes_packed=packed)
         return plan

@@ -24,8 +24,8 @@ import threading
 import time
 import warnings
 from concurrent.futures import ThreadPoolExecutor
-from dataclasses import dataclass
-from typing import Dict, Optional
+from dataclasses import dataclass, replace
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,8 +47,25 @@ from repro.store.feature_store import pad_feature_dim
 from repro.store.nbr_cache import SubgraphRowCache
 
 
+# the sg-mode structure arrays: the edge list and its carried extras
+EDGE_KEYS = ("edge_src", "edge_dst", "edge_w", "self_w", "edge_w_mean")
+
+
 def _pad128(f: int) -> int:
     return f + (-f) % 128
+
+
+def _with_edge_extras(sb: SubgraphBatch) -> SubgraphBatch:
+    """An externally constructed batch without the Build stage's carried
+    ``self_w``/``edge_w_mean``: recover them from the dense adjacency."""
+    n = sb.n
+    self_w = sb.adj[:, np.arange(n), np.arange(n)]
+    indeg = np.einsum("cij->ci", (sb.adj_mean > 0).astype(np.float32))
+    dst_deg = np.take_along_axis(np.maximum(indeg, 1.0),
+                                 sb.edge_dst.astype(np.int64), axis=1)
+    ew_mean = np.where(sb.edge_w != 0, 1.0 / dst_deg, 0.0)
+    return replace(sb, self_w=self_w.astype(np.float32),
+                   edge_w_mean=ew_mean.astype(np.float32))
 
 
 @dataclass
@@ -441,35 +458,24 @@ class DecoupledEngine:
         spelling of the staged pipeline, bitwise-identical to it."""
         return self.plan(targets).device
 
+    @property
+    def structure_keys(self) -> Tuple[str, ...]:
+        """The structure arrays the compiled program reads: the keys
+        ``device_batch`` returns besides ``feats``, and the only
+        ``SubgraphRows`` fields the Pack stage stacks (``adj_keys`` and
+        ``needs_edges`` already cover every mode vector a dispatching
+        engine may pick)."""
+        return ("mask",) + tuple(self.adj_keys) + \
+            (EDGE_KEYS if self.needs_edges else ())
+
     def device_batch(self, sb: SubgraphBatch,
                      include_feats: bool = True) -> Dict[str, np.ndarray]:
-        d = {"mask": sb.mask}
-        for k in self.adj_keys:     # only what the compiled program reads
-            d[k] = sb.adj if k == "adj" else sb.adj_mean
+        if self.needs_edges and (sb.self_w is None
+                                 or sb.edge_w_mean is None):
+            sb = _with_edge_extras(sb)
+        d = {k: getattr(sb, k) for k in self.structure_keys}
         if include_feats:
             d["feats"] = self._pad_feature_dim(sb.feats)
-        if self.needs_edges:
-            if sb.self_w is not None and sb.edge_w_mean is not None:
-                # Build-stage extras, computed from the CSR edge lists
-                d.update(edge_src=sb.edge_src, edge_dst=sb.edge_dst,
-                         edge_w=sb.edge_w, self_w=sb.self_w,
-                         edge_w_mean=sb.edge_w_mean)
-            else:
-                # externally constructed batch without the carried
-                # extras: recover them from the dense adjacency
-                n = sb.n
-                self_w = sb.adj[:, np.arange(n), np.arange(n)]
-                indeg = np.einsum("cij->ci",
-                                  (sb.adj_mean > 0).astype(np.float32))
-                d.update(edge_src=sb.edge_src, edge_dst=sb.edge_dst,
-                         edge_w=sb.edge_w,
-                         self_w=self_w.astype(np.float32))
-                valid = sb.edge_w != 0
-                dst_deg = np.take_along_axis(
-                    np.maximum(indeg, 1.0), sb.edge_dst.astype(np.int64),
-                    axis=1)
-                d["edge_w_mean"] = np.where(valid, 1.0 / dst_deg, 0.0
-                                            ).astype(np.float32)
         return d
 
     def run_device(self, device_batch) -> jax.Array:

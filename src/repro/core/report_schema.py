@@ -14,7 +14,7 @@ can detect drift:
               waits and device intervals ("waits"), achieved overlap
               fraction, batch count, Build-stage row-cache hit rate
   store.*     transfer + cache accounting (paper t_load / t_pre):
-              bytes_shipped / bytes_dense / transfer_ratio /
+              bytes_shipped / bytes_dense / bytes_packed / transfer_ratio /
               cache_hit_rate / dedup_ratio, plus the engine's store
               subsystem state (policy / features / nbr_cache /
               subgraph_cache / auto_repins)
@@ -72,12 +72,15 @@ Version history:
   7  ``stages.waits``: the scheduler's hand-off ledger (``queue.*`` waits
      and ``device.*`` intervals, ``SchedulerStats.wait_times``); ``times``
      stays service time only. Additive.
+  8  ``store.bytes_packed``: the bytes the Pack stage allocated for the
+     batches' structure arrays and payload, next to ``bytes_shipped``.
+     Additive.
 """
 from __future__ import annotations
 
 from typing import Optional
 
-SCHEMA_VERSION = 7
+SCHEMA_VERSION = 8
 
 # documented key map (stable contract; bump SCHEMA_VERSION on change)
 SCHEMA = {
@@ -85,9 +88,9 @@ SCHEMA = {
                 "p50", "p90", "p99", "mean", "batch_mean", "n", "hist"),
     "stages": ("times", "waits", "overlap", "batches", "build_hit_rate",
                "batch_edges"),
-    "store": ("bytes_shipped", "bytes_dense", "transfer_ratio",
-              "cache_hit_rate", "dedup_ratio", "policy", "features",
-              "nbr_cache", "subgraph_cache", "auto_repins",
+    "store": ("bytes_shipped", "bytes_dense", "bytes_packed",
+              "transfer_ratio", "cache_hit_rate", "dedup_ratio", "policy",
+              "features", "nbr_cache", "subgraph_cache", "auto_repins",
               "graph_hosts"),
     "shards": ("bytes", "balance"),
     "rpc": ("calls", "bytes_out", "bytes_in", "retries", "timeouts",
@@ -127,6 +130,7 @@ def store_section(stats) -> dict:
     merges its store-subsystem state into the same namespace)."""
     return {"bytes_shipped": stats.bytes_shipped,
             "bytes_dense": stats.bytes_dense,
+            "bytes_packed": stats.bytes_packed,
             "transfer_ratio": round(stats.transfer_ratio, 4),
             "cache_hit_rate": round(stats.cache_hit_rate, 4),
             "dedup_ratio": stats.last_dedup_ratio}

@@ -84,9 +84,11 @@ class SchedulerStats:
     # host->device transfer accounting (the paper's t_load, Eq. 2): what
     # actually crossed the link vs. what the dense baseline would ship,
     # plus the store's neighborhood-cache outcome — fed by the host side
-    # via ``PipelineScheduler.note_host_metrics``.
+    # via ``PipelineScheduler.note_host_metrics``. ``bytes_packed`` is
+    # what Pack allocated for the batches' structure arrays and payload.
     bytes_shipped: int = 0
     bytes_dense: int = 0
+    bytes_packed: int = 0
     cache_hits: int = 0
     cache_misses: int = 0
     # Build-stage subgraph-row cache outcome (staged pipelines only)
@@ -486,7 +488,8 @@ class PipelineScheduler:
         return t
 
     def note_host_metrics(self, *, bytes_shipped: int = 0,
-                          bytes_dense: int = 0, cache_hits: int = 0,
+                          bytes_dense: int = 0, bytes_packed: int = 0,
+                          cache_hits: int = 0,
                           cache_misses: int = 0, build_hits: int = 0,
                           build_misses: int = 0,
                           dedup_ratio: Optional[float] = None,
@@ -502,6 +505,7 @@ class PipelineScheduler:
             s = self.stats
             s.bytes_shipped += int(bytes_shipped)
             s.bytes_dense += int(bytes_dense)
+            s.bytes_packed += int(bytes_packed)
             s.cache_hits += int(cache_hits)
             s.cache_misses += int(cache_misses)
             s.build_hits += int(build_hits)
@@ -662,7 +666,8 @@ class PipelineScheduler:
             base = (self.stats.bytes_shipped, self.stats.bytes_dense,
                     self.stats.cache_hits, self.stats.cache_misses,
                     self.stats.build_hits, self.stats.build_misses,
-                    self.stats.batch_edges_total, self.stats.n_density)
+                    self.stats.batch_edges_total, self.stats.n_density,
+                    self.stats.bytes_packed)
         t0 = time.perf_counter()
         if not overlap or self.depth == 1:
             outs = []
@@ -714,5 +719,6 @@ class PipelineScheduler:
             call.build_misses = self.stats.build_misses - base[5]
             call.batch_edges_total = self.stats.batch_edges_total - base[6]
             call.n_density = self.stats.n_density - base[7]
+            call.bytes_packed = self.stats.bytes_packed - base[8]
             call.last_dedup_ratio = self.stats.last_dedup_ratio
         return outs, call

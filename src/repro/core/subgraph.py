@@ -31,6 +31,21 @@ from repro.core.ini import ini_batch
 from repro.graphs.csr import CSRGraph, subgraph_edges
 
 
+# every structure array a SubgraphRows carries, as one batch array each:
+# the field's batch dtype and its per-slot shape ("n" = receptive field,
+# "e" = edge budget)
+ROW_FIELDS = {
+    "adj": (np.float32, ("n", "n")),
+    "adj_mean": (np.float32, ("n", "n")),
+    "mask": (np.float32, ("n",)),
+    "edge_src": (np.int32, ("e",)),
+    "edge_dst": (np.int32, ("e",)),
+    "edge_w": (np.float32, ("e",)),
+    "self_w": (np.float32, ("n",)),
+    "edge_w_mean": (np.float32, ("e",)),
+}
+
+
 @dataclass(frozen=True)
 class SubgraphRows:
     """One target's built subgraph structure, padded to (n_pad, e_pad):
@@ -50,18 +65,14 @@ class SubgraphRows:
 
     def freeze(self) -> "SubgraphRows":
         """Mark every array read-only (cache entries are shared across
-        batches — assemble copies them into the batch tensors)."""
-        for a in (self.adj, self.adj_mean, self.mask, self.edge_src,
-                  self.edge_dst, self.edge_w, self.self_w,
-                  self.edge_w_mean):
-            a.flags.writeable = False
+        batches — Pack and assemble_batch copy them into batch arrays)."""
+        for f in ROW_FIELDS:
+            getattr(self, f).flags.writeable = False
         return self
 
     @property
     def nbytes(self) -> int:
-        return sum(a.nbytes for a in (
-            self.adj, self.adj_mean, self.mask, self.edge_src,
-            self.edge_dst, self.edge_w, self.self_w, self.edge_w_mean))
+        return sum(getattr(self, f).nbytes for f in ROW_FIELDS)
 
 
 @dataclass(frozen=True)
@@ -217,41 +228,58 @@ def build_batch(g: CSRGraph, targets, n: int, e_pad: Optional[int] = None,
     return batch_from_node_lists(g, targets, node_lists, n, e_pad)
 
 
+def stack_rows(rows: List[SubgraphRows], fields, n: int,
+               e_pad: int) -> Dict[str, np.ndarray]:
+    """Stack the named ``ROW_FIELDS`` of per-target rows into [C, ...]
+    batch arrays, and allocate no other. A run of slots holding the same
+    row object (pad_targets' repeated tail under the row cache) is one
+    broadcast assignment; every other slot is one copy."""
+    dims = {"n": n, "e": e_pad}
+    out = {}
+    for f in fields:
+        dtype, shape = ROW_FIELDS[f]
+        out[f] = np.empty((len(rows),) + tuple(dims[d] for d in shape),
+                          dtype)
+    i = 0
+    while i < len(rows):
+        r, j = rows[i], i + 1
+        while j < len(rows) and rows[j] is r:
+            j += 1
+        for f, a in out.items():
+            a[i:j] = getattr(r, f)
+        i = j
+    return out
+
+
+def host_feats(g: CSRGraph, node_lists: List[np.ndarray],
+               n: int) -> np.ndarray:
+    """The dense [C, n, f] feature block of a batch's node lists (zero
+    rows past each list's end), for stores that ship it from the host."""
+    feats = np.zeros((len(node_lists), n, g.feature_dim), np.float32)
+    for i, nl in enumerate(node_lists):
+        nodes = nl[:n]
+        feats[i, :len(nodes)] = g.features[nodes]
+    return feats
+
+
 def assemble_batch(g: CSRGraph, targets, node_lists: List[np.ndarray],
                    rows: List[SubgraphRows], n: int, e_pad: int,
                    build_feats: bool = True) -> SubgraphBatch:
     """Pack per-target built rows into one fixed-shape SubgraphBatch
-    (the Pack stage's structure half; features are materialized here only
-    for strategies that ship the dense block)."""
+    holding EVERY structure array (dense adjacencies, edge lists and
+    their sg-mode extras) plus the per-slot counts — the full batch for
+    offline use, training and tests. Features are materialized only with
+    ``build_feats``. Serving's Pack stage does not call this: it stacks
+    only the arrays its compiled program reads (``stack_rows``)."""
     C = len(rows)
-    f = g.feature_dim if build_feats else 0   # [C, n, 0]: shape carriers
-    feats = np.zeros((C, n, f), np.float32)   # (n, batch_size) stay valid
-    adj = np.zeros((C, n, n), np.float32)
-    adj_mean = np.zeros((C, n, n), np.float32)
-    mask = np.zeros((C, n), np.float32)
-    es = np.zeros((C, e_pad), np.int32)
-    ed = np.zeros((C, e_pad), np.int32)
-    ew = np.zeros((C, e_pad), np.float32)
-    self_w = np.zeros((C, n), np.float32)
-    ew_mean = np.zeros((C, e_pad), np.float32)
-    nv = np.zeros(C, np.int32)
-    ne = np.zeros(C, np.int32)
-    dropped = 0
-    for i, r in enumerate(rows):
-        adj[i], adj_mean[i], mask[i] = r.adj, r.adj_mean, r.mask
-        es[i], ed[i], ew[i] = r.edge_src, r.edge_dst, r.edge_w
-        self_w[i], ew_mean[i] = r.self_w, r.edge_w_mean
-        nv[i], ne[i] = r.n_vertices, r.n_edges
-        dropped += r.edges_dropped
-        if build_feats:
-            nodes = node_lists[i][:n]
-            feats[i, :len(nodes)] = g.features[nodes]
-    return SubgraphBatch(feats=feats, adj=adj, adj_mean=adj_mean, mask=mask,
-                         edge_src=es, edge_dst=ed, edge_w=ew,
-                         n_vertices=nv, n_edges=ne,
-                         targets=np.asarray(targets, np.int64),
-                         edges_dropped=dropped,
-                         self_w=self_w, edge_w_mean=ew_mean)
+    a = stack_rows(rows, ROW_FIELDS, n, e_pad)
+    feats = host_feats(g, node_lists, n) if build_feats \
+        else np.zeros((C, n, 0), np.float32)  # shape carriers: n and
+    return SubgraphBatch(                     # batch_size stay valid
+        feats=feats, targets=np.asarray(targets, np.int64),
+        n_vertices=np.array([r.n_vertices for r in rows], np.int32),
+        n_edges=np.array([r.n_edges for r in rows], np.int32),
+        edges_dropped=sum(r.edges_dropped for r in rows), **a)
 
 
 def batch_from_node_lists(g: CSRGraph, targets, node_lists: List[np.ndarray],

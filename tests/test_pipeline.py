@@ -11,10 +11,13 @@ import numpy as np
 import pytest
 
 from repro.core.batchplan import BatchPlan
+from repro.core.config import ServingConfig
+from repro.core.dispatch import DispatchConfig
 from repro.core.engine import DecoupledEngine
 from repro.core.ini import ini_batch
 from repro.core.scheduler import PipelineScheduler
-from repro.core.subgraph import batch_from_node_lists, build_batch
+from repro.core.subgraph import (assemble_batch, batch_from_node_lists,
+                                 build_batch)
 from repro.gnn.model import GNNConfig, init_gnn
 from repro.graphs.synthetic import get_graph, zipf_traffic
 from repro.serve.gnn_server import GNNServer
@@ -136,11 +139,86 @@ class TestStagedEqualsMonolithic:
             assert len(plan.node_lists) == C
             assert len(plan.rows) == C
             assert plan.rows[0].adj.shape == (N, N)
-            assert plan.sb.batch_size == C
             assert plan.device is not None
+            assert plan.device["mask"].shape == (C, N)
             assert plan.nbr_misses == C    # cold cache
             # frontiers cached for exact invalidation
             assert all(f is not None for f in plan.frontiers.values())
+
+
+PACK_MODES = {
+    "dense-auto": dict(mode="auto"),
+    "sg": dict(mode="sg"),
+    "dispatch": dict(mode="auto", dispatch=DispatchConfig(
+        warmup_passes=0, autotune_blocks=False)),
+}
+
+
+class TestPackBuildsWhatIsRead:
+    """Pack stacks only the arrays the compiled program reads, straight
+    from the built rows: the device dict is bitwise the full-batch
+    spelling's (``assemble_batch`` + ``device_batch``) on every key."""
+    CP = 8             # 3 real targets + a padded tail of 5
+
+    def _engine(self, graph, kind, features="resident", **serving):
+        return DecoupledEngine(
+            graph, _cfg(kind, graph), config=ServingConfig(
+                batch_size=self.CP, seed=3, num_threads=1,
+                store=StorePolicy(features=features, nbr_cache="lru",
+                                  nbr_capacity=64), **serving))
+
+    @staticmethod
+    def _packed(eng, targets):
+        """Select and Build, then Pack; also the device dict Pack used
+        to ship for the same rows: the full batch, cut to ``mask``, the
+        program's adjacencies and (when it reads them) the edge arrays,
+        plus the store's payload."""
+        plan = BatchPlan(targets=eng.pad_targets(np.asarray(targets)))
+        for stage in eng.stages[:-1]:
+            plan = stage.run(plan)
+        n, src = eng.cfg.receptive_field, eng._fsource
+        sb = assemble_batch(eng.graph, plan.targets, plan.node_lists,
+                            plan.rows, n, eng.e_pad,
+                            build_feats=src.needs_host_feats)
+        want = {"mask": sb.mask}
+        for k in eng.adj_keys:
+            want[k] = sb.adj if k == "adj" else sb.adj_mean
+        if eng.needs_edges:
+            want.update(edge_src=sb.edge_src, edge_dst=sb.edge_dst,
+                        edge_w=sb.edge_w, self_w=sb.self_w,
+                        edge_w_mean=sb.edge_w_mean)
+        assert eng.device_batch(sb, include_feats=False).keys() \
+            == want.keys()
+        payload, _ = src.host_payload(
+            plan.node_lists, n, sb.feats if src.needs_host_feats else None)
+        want.update(payload)
+        return eng.stages[-1].run(plan).device, want
+
+    @pytest.mark.parametrize("features", ("dense", "resident"))
+    @pytest.mark.parametrize("serving", tuple(PACK_MODES))
+    @pytest.mark.parametrize("kind", ("gcn", "gat", "sage"))
+    def test_device_dict_bitwise_full_batch(self, graph, kind, serving,
+                                            features):
+        with self._engine(graph, kind, features,
+                          **PACK_MODES[serving]) as eng:
+            got, want = self._packed(eng, TARGETS[:3])
+            assert got.keys() == want.keys()
+            for k, a in want.items():
+                assert got[k].dtype == a.dtype, k
+                np.testing.assert_array_equal(got[k], a, err_msg=k)
+            if serving != "dense-auto":
+                assert "edge_src" in got and "self_w" in got
+
+    def test_dense_gcn_packs_only_adj_and_mask(self, graph):
+        with self._engine(graph, "gcn") as eng:
+            got, _ = self._packed(eng, TARGETS[:3])
+            assert set(got) == {"mask", "adj",
+                                *eng._fsource.payload_keys}
+            s = eng.scheduler.stats
+            assert s.bytes_packed == s.bytes_shipped \
+                == sum(a.nbytes for a in got.values())
+            assert eng.scheduler.stats.summary()["store"][
+                "bytes_packed"] == s.bytes_packed
 
 
 class TestSubgraphRowCache:
