@@ -1,7 +1,13 @@
-"""Operations and bytes, counted from shapes: the model's work per target
-(for ``ack_step_mfu``) and each Pallas kernel's work per call (for the
-kernel rooflines). Kept with the benchmark so that no later change to the
-program can recount them.
+"""Operations and bytes, counted from shapes: the arithmetic that the
+model's work per target (for ``ack_step_mfu``) and each Pallas kernel's
+work per call (for the kernel rooflines) are built from. Kept with the
+benchmark so that no later change to the program can recount them.
+
+Each count lives in the file that its kind or kernel brings:
+
+  bench/models/<kind>.py      ``model_flops(model, n_vertices, n_edges)``
+                              and ``FUSED_USES``
+  bench/kernels/<kernel>.py   ``count(operands, out, model)``
 
 Model work counts what the model needs, whatever implements it:
 transforms dense over the field's real vertices at the unpadded widths
@@ -18,35 +24,22 @@ use is not counted, so moving it anyway shows as a lower roofline share.
 """
 from __future__ import annotations
 
+import functools
+import os
 from typing import List, Tuple
 
 F32 = 4
 # VPU work per attention score and head: add, LeakyReLU, mask, max,
 # subtract, exp, sum, divide
 ATTN_ELEMENTWISE = 8
+KERNELS_DIR = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "kernels")
 
 
-def _widths(model: dict) -> List[Tuple[int, int]]:
+def widths(model: dict) -> List[Tuple[int, int]]:
+    """(f_in, f_out) of each layer."""
     f_in, f = int(model["f_in"]), int(model["f_hidden"])
     return [(f_in, f)] + [(f, f)] * (int(model["n_layers"]) - 1)
-
-
-def model_flops(model: dict, n_vertices: int, n_edges: int) -> float:
-    """The model's operations for one target whose receptive field has
-    ``n_vertices`` vertices and ``n_edges`` directed edges."""
-    k, e = float(n_vertices), float(n_edges)
-    kind = model["kind"]
-    total = 0.0
-    for fi, fo in _widths(model):
-        total += 2 * k * fi * fo                       # transform
-        total += 2 * (e + k) * fo                      # aggregation
-        if kind == "gat":
-            heads = int(model["n_heads"])
-            total += 2 * 2 * k * fo                    # s_src, s_dst
-            total += ATTN_ELEMENTWISE * (e + k) * heads
-        elif kind != "gcn":
-            raise ValueError(f"no model count for kind {kind!r}")
-    return total
 
 
 def fused_gnn_layer(c: int, n: int, f_in: int, f_out: int,
@@ -68,10 +61,20 @@ def gat_attention(c: int, n: int, f: int, heads: int) -> Tuple[float, float]:
     return ops, float(moved * F32)
 
 
-# which operands of a fused_gnn_layer call each model kind's program uses:
-# GCN aggregates (A (H W_neigh)); GAT's transform only applies W_self
-FUSED_USES = {"gcn": ("adj", "w_neigh"), "gat": ("w_self",)}
-FUSED_OPERANDS = ("adj", "h", "w_neigh", "w_self", "b", "mask")
+def kernel_names() -> Tuple[str, ...]:
+    """The Pallas kernels that have a count file, in sorted order."""
+    return tuple(sorted(f[:-len(".py")] for f in os.listdir(KERNELS_DIR)
+                        if f.endswith(".py")))
+
+
+@functools.lru_cache(maxsize=None)
+def kernel_count(kernel: str):
+    """``count`` of ``bench/kernels/<kernel>.py``."""
+    path = os.path.join(KERNELS_DIR, f"{kernel}.py")
+    if not os.path.isfile(path):
+        raise ValueError(f"no count for kernel {kernel!r}: {path} is missing")
+    from bench.harness import load_module
+    return load_module(path, f"bench_kernel_{kernel}").count
 
 
 def _nbytes(array) -> int:
@@ -82,27 +85,17 @@ def _nbytes(array) -> int:
     return n
 
 
-def kernel_call(kernel: str, arrays: list, model: dict) -> Tuple[float, float]:
+def kernel_call(kernel: str, arrays: list, model) -> Tuple[float, float]:
     """(operations, bytes that must cross HBM) of one traced kernel call.
     ``arrays`` is [output, operand, ...], each [shape, item bytes, memory
-    space] as the trace's op text gives them; an array the compiler keeps
-    in on-chip memory (space 1) moves no HBM bytes, and an operand the
-    call is handed but does not use counts nothing."""
+    space] as the trace's op text gives them; ``model`` is the cell's
+    model module (``bench/models/<kind>.py``). The kernel's count file
+    gives the operations and the arrays the call needs; of those, an
+    array the compiler keeps in on-chip memory (space 1) moves no HBM
+    bytes, and an operand the call is handed but does not use counts
+    nothing."""
     out, *operands = arrays
-    if kernel == "fused_gnn_layer":
-        named = dict(zip(FUSED_OPERANDS, operands))
-        c, n, f_in = named["h"][0]
-        f_out = out[0][-1]
-        uses = FUSED_USES[model["kind"]]
-        ops, _ = fused_gnn_layer(c, n, f_in, f_out, "adj" in uses)
-        needed = [out] + [named[k] for k in ("h", "b", "mask") + uses]
-    elif kernel == "gat_attention":
-        z, s_src = operands[0], operands[1]
-        c, n, f = z[0]
-        ops, _ = gat_attention(c, n, f, s_src[0][-1])
-        needed = [out] + list(operands)
-    else:
-        raise ValueError(f"no count for kernel {kernel!r}")
+    ops, needed = kernel_count(kernel)(operands, out, model)
     return ops, float(sum(_nbytes(a) for a in needed if a[2] == 0))
 
 

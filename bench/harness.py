@@ -5,11 +5,20 @@ Everything that belongs to one configuration, traffic mix or per-layer
 metric is a file found by its name in ``BENCHMARK.json``:
 
   bench/configs/<config>.json     sizes, serving settings, check limits
-  bench/models/<kind>.py          weights and the plain reference model
+  bench/models/<kind>.py          weights, the plain reference model and
+                                  its counts (``model_flops``,
+                                  ``FUSED_USES``)
+  bench/kernels/<kernel>.py       one Pallas kernel's count per call
   bench/traffic/<mix>.json        rate, target law and warm-up size;
   bench/traffic/<law>.py          the target sampler the mix names
-  bench/metrics/<metric>.py       one per-layer metric reader
+  bench/metrics/<metric>.py       one per-layer metric reader; its
+                                  ``MODEL_NEEDS`` names what it reads
+                                  from the model module
   bench/peaks.json                chip peaks by ``device_kind``
+
+The configuration's ``model`` and ``serving`` groups reach the program
+key by key: each key is the field of that name of ``GNNConfig`` and
+``ServingConfig``, converted to the field's type.
 
 From the program this file takes the system under test: the graph
 constructor, ``GNNServer`` with its engine, and the engine's counters.
@@ -17,12 +26,14 @@ constructor, ``GNNServer`` with its engine, and the engine's counters.
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import gc
 import importlib.util
 import json
 import os
 import sys
 import time
+import typing
 from dataclasses import dataclass
 from typing import Callable, Dict, List, Optional
 
@@ -74,15 +85,23 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     bench_dir: str
+    _module: object = dataclasses.field(default=None, init=False,
+                                        repr=False, compare=False)
 
     @property
     def model(self) -> dict:
         return self.config["model"]
 
+    def model_module_path(self) -> str:
+        return os.path.join(self.bench_dir, "models",
+                            f"{self.model['kind']}.py")
+
     def model_module(self):
-        kind = self.model["kind"]
-        return load_module(os.path.join(self.bench_dir, "models",
-                                        f"{kind}.py"), f"bench_model_{kind}")
+        """``bench/models/<kind>.py``, loaded once."""
+        if self._module is None:
+            self._module = load_module(self.model_module_path(),
+                                       f"bench_model_{self.model['kind']}")
+        return self._module
 
     def metric_readers(self) -> Dict[str, object]:
         """This cell's per-layer readers, each checked against its entry
@@ -117,11 +136,20 @@ def load_cell(root: str, name: str) -> Cell:
     config = load_json(os.path.join(root, cfg_entry["file"]))
     mix = loadgen.load_mix(entry["traffic"],
                            os.path.join(bench_dir, "traffic"))
-    return Cell(name=name, workload=entry, config=config, mix=mix,
+    cell = Cell(name=name, workload=entry, config=config, mix=mix,
                 end_to_end=[m for m in bm["end_to_end"]
                             if applies(m, name)],
                 per_layer=[m for m in bm["per_layer"] if applies(m, name)],
                 bench_dir=bench_dir)
+    module = cell.model_module()
+    for metric, reader in cell.metric_readers().items():
+        for need in getattr(reader, "MODEL_NEEDS", ()):
+            if not hasattr(module, need):
+                raise ValueError(
+                    f"{cell.model_module_path()} has no {need}, which "
+                    f"metric {metric} of {name} needs")
+    program_configs(cell)
+    return cell
 
 
 # ---------------------------------------------------------------------------
@@ -191,24 +219,50 @@ def make_weights(cell: Cell, seed: int):
     return jax.jit(lambda k: init(k, model))(jax.random.PRNGKey(key_seed))
 
 
-def deploy(cell: Cell, graph, seed: int) -> Deployment:
-    """The cell's server with one lane, on the seed's weights."""
+def from_config(cls, values: dict, where: str):
+    """``cls(**values)``, each value converted to its field's type: int,
+    float, str and bool by that type, a nested dataclass (``store`` to
+    ``StorePolicy``) key by key, a tuple from a list, ``None`` where the
+    field is optional. A key ``cls`` has no field for is refused."""
+    hints = typing.get_type_hints(cls)
+    names = {f.name for f in dataclasses.fields(cls)}
+    for key in values:
+        if key not in names:
+            raise ValueError(f"configuration key {where}.{key}: "
+                             f"{cls.__name__} has no field {key!r}")
+    return cls(**{k: _as_type(hints[k], v, f"{where}.{k}")
+                  for k, v in values.items()})
+
+
+def _as_type(hint, value, where: str):
+    if dataclasses.is_dataclass(hint):
+        return from_config(hint, value, where)
+    args = [a for a in typing.get_args(hint) if a is not type(None)]
+    if typing.get_origin(hint) is typing.Union:
+        if value is None:
+            return None
+        return _as_type(args[0], value, where) if len(args) == 1 else value
+    if typing.get_origin(hint) is tuple:
+        return tuple(value)
+    if hint in (int, float, str, bool):
+        return hint(value)
+    return value
+
+
+def program_configs(cell: Cell):
+    """The program's ``GNNConfig`` and ``ServingConfig`` from the
+    configuration's ``model`` and ``serving`` groups."""
     from repro.core.config import ServingConfig
     from repro.gnn.model import GNNConfig
-    from repro.serve.gnn_server import GNNServer
-    from repro.store import StorePolicy
+    return (from_config(GNNConfig, cell.model, "model"),
+            from_config(ServingConfig, cell.config["serving"], "serving"))
 
-    m, s = cell.model, cell.config["serving"]
-    gcfg = GNNConfig(kind=m["kind"], n_layers=int(m["n_layers"]),
-                     receptive_field=int(m["receptive_field"]),
-                     f_in=int(m["f_in"]), f_hidden=int(m["f_hidden"]),
-                     n_heads=int(m["n_heads"]), readout=m["readout"],
-                     ppr_alpha=float(m["ppr_alpha"]),
-                     ppr_eps=float(m["ppr_eps"]))
-    sconf = ServingConfig(batch_size=int(s["batch_size"]), impl=s["impl"],
-                          mode=s["mode"], num_threads=int(s["num_threads"]),
-                          max_wait_s=float(s["max_wait_s"]),
-                          store=StorePolicy(**s["store"]))
+
+def deploy(cell: Cell, graph, seed: int) -> Deployment:
+    """The cell's server with one lane, on the seed's weights."""
+    from repro.serve.gnn_server import GNNServer
+
+    gcfg, sconf = program_configs(cell)
     params = make_weights(cell, seed)
     server = GNNServer(max_wait_s=sconf.max_wait_s)
     lane = cell.config["name"]

@@ -6,6 +6,7 @@ UNIT = "%"
 SOURCE = "device_trace"
 MOVES = "latency_p50_ms"
 BETTER = "higher"
+MODEL_NEEDS = ("model_flops",)
 
 
 def read(run):

@@ -13,5 +13,5 @@ KERNEL = "gat_attention"
 def read(run):
     if run.trace is None:
         return None
-    return tracing.kernel_roofline(run.trace, KERNEL, run.cell.model,
-                                   run.peaks)
+    return tracing.kernel_roofline(run.trace, KERNEL,
+                                   run.cell.model_module(), run.peaks)

@@ -8,6 +8,7 @@ UNIT = "%"
 SOURCE = "device_trace"
 MOVES = "latency_p50_ms"
 BETTER = "higher"
+MODEL_NEEDS = ("FUSED_USES",)
 
 
 def read(run):
@@ -15,8 +16,9 @@ def read(run):
     if t is None:
         return None
     bound = total = 0.0
+    model = run.cell.model_module()
     for kernel in t.kernel_seconds:
-        share = tracing.kernel_roofline(t, kernel, run.cell.model, run.peaks)
+        share = tracing.kernel_roofline(t, kernel, model, run.peaks)
         if share is not None:
             secs = t.kernel_seconds[kernel]
             bound += share / 100.0 * secs

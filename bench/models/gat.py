@@ -5,11 +5,19 @@ on the target's receptive field: per layer
     e[i, j] = LeakyReLU_0.2(s_dst[i] + s_src[j])  for j -> i or j = i
     h <- elu(softmax_j(e) z + b) * mask
 
-then the element-wise max over the field's vertices."""
+then the element-wise max over the field's vertices.
+
+Its work per target is ``model_flops`` (bench/flops.py), and the
+operands of a ``fused_gnn_layer`` call that its program uses are
+``FUSED_USES``: the transform applies W_self alone."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from bench import flops
+
+FUSED_USES = ("w_self",)
 
 
 def init(key, model: dict):
@@ -72,3 +80,18 @@ def forward(params, x, model, dtype=None):
         out = jnp.einsum("chij,cjhf->cihf", attn, z4).reshape(c, n, f)
         h = jax.nn.elu(out + cast(p["b"])) * mask[..., None]
     return jnp.max(jnp.where(mask[..., None] > 0, h, -jnp.inf), axis=1)
+
+
+def model_flops(model: dict, n_vertices: int, n_edges: int) -> float:
+    """Operations for one target whose receptive field has ``n_vertices``
+    vertices and ``n_edges`` directed edges: GCN's transform and
+    aggregation, the score terms and the softmax per head and edge."""
+    k, e = float(n_vertices), float(n_edges)
+    heads = int(model["n_heads"])
+    total = 0.0
+    for fi, fo in flops.widths(model):
+        total += 2 * k * fi * fo                       # transform
+        total += 2 * (e + k) * fo                      # aggregation
+        total += 2 * 2 * k * fo                        # s_src, s_dst
+        total += flops.ATTN_ELEMENTWISE * (e + k) * heads
+    return total

@@ -5,11 +5,19 @@
 
 then the element-wise max over the field's vertices. ``A_hat (h W)`` is
 evaluated in that order, as the program's fused kernel does, so that
-both round the same matmul operands at the configuration's precision."""
+both round the same matmul operands at the configuration's precision.
+
+Its work per target is ``model_flops`` (bench/flops.py), and the
+operands of a ``fused_gnn_layer`` call that its program uses are
+``FUSED_USES``: the program aggregates, A (H W_neigh)."""
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+
+from bench import flops
+
+FUSED_USES = ("adj", "w_neigh")
 
 
 def init(key, model: dict):
@@ -44,3 +52,14 @@ def forward(params, x, model, dtype=None):
         z = jnp.einsum("cij,cjg->cig", adj, hw) + cast(p["b"])
         h = jax.nn.relu(z) * mask[..., None]
     return jnp.max(jnp.where(mask[..., None] > 0, h, -jnp.inf), axis=1)
+
+
+def model_flops(model: dict, n_vertices: int, n_edges: int) -> float:
+    """Operations for one target whose receptive field has ``n_vertices``
+    vertices and ``n_edges`` directed edges."""
+    k, e = float(n_vertices), float(n_edges)
+    total = 0.0
+    for fi, fo in flops.widths(model):
+        total += 2 * k * fi * fo                       # transform
+        total += 2 * (e + k) * fo                      # aggregation
+    return total

@@ -34,8 +34,9 @@ from bench import flops
 
 SPAN_PREFIX = "bench."
 WINDOW_SPAN = "bench.window"
-# the Pallas kernels, by the name their custom calls carry in the trace
-KERNELS = ("fused_gnn_layer", "gat_attention", "scatter_gather_aggregate")
+# the Pallas kernels, by the name their custom calls carry in the trace:
+# one count file each in bench/kernels
+KERNELS = flops.kernel_names()
 DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
@@ -208,12 +209,13 @@ def reduce(tr: dict) -> Summary:
                    idle_by_span=idle)
 
 
-def kernel_roofline(summary: Summary, kernel: str, model: dict,
+def kernel_roofline(summary: Summary, kernel: str, model,
                     peaks: dict) -> Optional[float]:
     """Share (%) of the least time the chip could take for ``kernel``'s
     calls in the served program's whole runs inside the traced window
-    (each call counted at its own traced shapes, ``flops.kernel_call``),
-    over those calls' measured device time."""
+    (each call counted at its own traced shapes, ``flops.kernel_call``,
+    for the cell's model module ``model``), over those calls' measured
+    device time."""
     t = summary.kernel_seconds.get(kernel, 0.0)
     calls = summary.kernel_calls.get(kernel, [])
     if t <= 0 or not calls:
@@ -232,13 +234,16 @@ class WindowTracer:
     set-up until the window has drained (so that starting and stopping it,
     which stall the whole process, fall outside the window), the window
     marked by a span of its own, and what the reduction needs from the
-    host side: each device call's real targets and their model work."""
+    host side: each device call's real targets and their model work, by
+    the ``model_flops`` of the cell's model module (none where it has
+    none; ``harness.load_cell`` refuses a cell whose metrics need it)."""
 
     def __init__(self, dep, cell):
         import jax
         self.jax = jax
         self.cell = cell
         self.model = cell.model
+        self._count = getattr(cell.model_module(), "model_flops", None)
         self._real: Dict[int, int] = {}
         self._calls: List[Tuple[float, float]] = []   # (t, model flops)
         self._lock = threading.Lock()
@@ -269,13 +274,14 @@ class WindowTracer:
             return ticket
         eng.submit_chunk = submit_chunk
         inner_device = self._annotated("device", eng.scheduler.device_fn)
-        model = self.model
+        model, count = self.model, self._count
 
         def device(plan):
             with self._lock:
                 real = self._real.pop(id(plan.targets), len(plan.targets))
-            work = sum(flops.model_flops(model, r.n_vertices, r.n_edges)
-                       for r in (plan.rows or [])[:real])
+            work = 0.0 if count is None else sum(
+                count(model, r.n_vertices, r.n_edges)
+                for r in (plan.rows or [])[:real])
             with self._lock:
                 self._calls.append((time.perf_counter(), work))
             return inner_device(plan)

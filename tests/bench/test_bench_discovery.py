@@ -4,12 +4,13 @@ and entries alone."""
 import json
 import os
 import re
+import shutil
 
 import numpy as np
 import pytest
 
-from bench_tiny_root import REPO, make_root
-from bench import harness, loadgen
+from bench_tiny_root import REPO, make_root, tiny_config
+from bench import harness, loadgen, tracing
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
@@ -57,9 +58,19 @@ def test_every_cell_resolves_its_files(cell):
     loadgen.sampler(c.mix["targets"]["kind"])
 
 
-def test_new_files_are_found_without_editing_any_other(tmp_path):
+def test_new_files_are_found_without_editing_any_other(tmp_path,
+                                                       monkeypatch):
     root = make_root(tmp_path, kinds=("gcn",))
     bench = os.path.join(root, "bench")
+    # a model kind of its own: its config and its model module, with the
+    # reference, the model's work and the fused kernel's operands
+    shutil.copy(os.path.join(os.path.dirname(__file__), "sage_model.py"),
+                os.path.join(bench, "models", "sage.py"))
+    sage = tiny_config("gcn")
+    sage["name"] = "sage-tiny"
+    sage["model"]["kind"] = "sage"
+    with open(os.path.join(bench, "configs", "sage-tiny.json"), "w") as f:
+        json.dump(sage, f)
     with open(os.path.join(bench, "traffic", "hot.py"), "w") as f:
         f.write("import numpy as np\n\n\n"
                 "def sample(rng, degrees, n, *, vertex):\n"
@@ -76,6 +87,14 @@ def test_new_files_are_found_without_editing_any_other(tmp_path):
         bm = json.load(f)
     bm["workloads"].append({"name": "gcn-tiny.hot", "config": "gcn-tiny",
                             "traffic": "hot-one", "chips": 1, "why": "t"})
+    bm["configs"].append({"name": "sage-tiny", "source": "test",
+                          "file": "bench/configs/sage-tiny.json",
+                          "reduced": [], "why": "test"})
+    bm["workloads"].append({"name": "sage-tiny.zipf", "config": "sage-tiny",
+                            "traffic": "zipf-tiny", "chips": 1, "why": "t"})
+    for m in bm["per_layer"]:
+        if m["name"] != "gat_attention_roofline":
+            m["workloads"].append("sage-tiny.zipf")
     bm["per_layer"].append({"name": "requests_seen", "unit": "count",
                             "better": "higher", "source": "host_clock",
                             "layer": "load generator",
@@ -97,6 +116,28 @@ def test_new_files_are_found_without_editing_any_other(tmp_path):
     # the old cell is untouched by the new entries
     assert "requests_seen" not in {
         m["name"] for m in harness.load_cell(root, "gcn-tiny.zipf").per_layer}
+    # the new kind serves, checks and is counted by its own module
+    monkeypatch.setattr(harness, "enable_compile_cache", lambda root: None)
+    tracers = []
+
+    class Tracer(tracing.WindowTracer):
+        def __init__(self, dep, cell):
+            super().__init__(dep, cell)
+            tracers.append(self)
+    monkeypatch.setattr(tracing, "WindowTracer", Tracer)
+    r = harness.run(root, "sage-tiny.zipf", 2 ** 31 + 7, 1.5, True,
+                    need_chip=False)
+    assert r["correct"] is True and r["failed"] == 0
+    (tracer,) = tracers
+    module = tracer.cell.model_module()
+    assert tracer.cell.model["kind"] == "sage"
+    assert module.__file__ == os.path.join(bench, "models", "sage.py")
+    work = [w for _, w in tracer._calls]
+    counted = list(module.CALLS)
+    assert work and all(w > 0 for w in work)
+    assert len(counted) >= r["attempted"]
+    assert sum(work) == pytest.approx(sum(
+        module.model_flops(sage["model"], k, e) for k, e in counted))
 
 
 def test_a_reader_that_disagrees_with_its_entry_is_refused(tmp_path):
@@ -108,3 +149,63 @@ def test_a_reader_that_disagrees_with_its_entry_is_refused(tmp_path):
         json.dump(bm, f)
     with pytest.raises(ValueError, match="layer"):
         harness.load_cell(root, "gcn-tiny.zipf").metric_readers()
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_program_configs_are_built_as_before(cell):
+    """Every key of ``model`` and ``serving`` reaches the program: the
+    configs come out field by field as the nine and five keys named one
+    by one built them."""
+    import dataclasses
+    from repro.core.config import ServingConfig
+    from repro.gnn.model import GNNConfig
+    from repro.store import StorePolicy
+    c = harness.load_cell(REPO, cell)
+    m, s = c.model, c.config["serving"]
+    want_g = GNNConfig(kind=m["kind"], n_layers=int(m["n_layers"]),
+                       receptive_field=int(m["receptive_field"]),
+                       f_in=int(m["f_in"]), f_hidden=int(m["f_hidden"]),
+                       n_heads=int(m["n_heads"]), readout=m["readout"],
+                       ppr_alpha=float(m["ppr_alpha"]),
+                       ppr_eps=float(m["ppr_eps"]))
+    want_s = ServingConfig(batch_size=int(s["batch_size"]), impl=s["impl"],
+                           mode=s["mode"], num_threads=int(s["num_threads"]),
+                           max_wait_s=float(s["max_wait_s"]),
+                           store=StorePolicy(**s["store"]))
+    got_g, got_s = harness.program_configs(c)
+    for got, want in ((got_g, want_g), (got_s, want_s),
+                      (got_s.store, want_s.store)):
+        for f in dataclasses.fields(want):
+            a, b = getattr(got, f.name), getattr(want, f.name)
+            assert a == b and type(a) is type(b), f.name
+
+
+@pytest.mark.parametrize("group,key", [("model", "aggregators"),
+                                       ("serving", "lanes"),
+                                       ("serving.store", "shards")])
+def test_a_config_key_the_program_lacks_is_refused_at_load(tmp_path, group,
+                                                           key):
+    root = make_root(tmp_path, kinds=("gcn",))
+    path = os.path.join(root, "bench", "configs", "gcn-tiny.json")
+    with open(path) as f:
+        cfg = json.load(f)
+    target = cfg
+    for part in group.split("."):
+        target = target[part]
+    target[key] = 1
+    with open(path, "w") as f:
+        json.dump(cfg, f)
+    with pytest.raises(ValueError, match=rf"{group}\.{key}: "):
+        harness.load_cell(root, "gcn-tiny.zipf")
+
+
+def test_config_values_take_their_field_types():
+    from repro.core.config import ServingConfig
+    s = harness.from_config(ServingConfig, {
+        "batch_size": 8.0, "e_pad": None, "transport": "socket",
+        "endpoints": ["a:1", "b:2"],
+        "store": {"nbr_cache": "pinned", "nbr_capacity": "64",
+                  "pinned_targets": [3, 4]}}, "serving")
+    assert s.batch_size == 8 and type(s.batch_size) is int
+    assert s.e_pad is None and s.endpoints == ("a:1", "b:2")
+    assert s.store.nbr_capacity == 64 and s.store.pinned_targets == (3, 4)

@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from bench_tiny_root import REPO
-from bench import flops, tracing
+from bench import flops, harness, tracing
 
 # a fused GCN layer call's arrays, [shape, item bytes, memory space]:
 # output, adj, h, w_neigh, w_self, b, mask; w_self and the output on-chip
@@ -79,9 +79,14 @@ def test_union_merges_touching_and_nested_intervals():
         (0, 4), (5, 7)]
 
 
+def model_module(kind):
+    return harness.load_module(os.path.join(REPO, "bench", "models",
+                                            f"{kind}.py"), f"test_{kind}")
+
+
 def test_kernel_roofline_is_bound_time_over_measured_time():
     s = tracing.reduce(HAND)
-    model = {"kind": "gcn"}
+    model = model_module("gcn")
     peaks = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
     ops, moved = flops.kernel_call("fused_gnn_layer", FUSED, model)
     want = max(ops / 1e12, moved / 1e9) / 100e-9
@@ -101,6 +106,33 @@ def test_kernel_arrays_are_read_from_the_op_text():
     assert tracing.arrays_of(text) == [[[64, 128, 256], 4, 1],
                                        [[64, 128, 128], 4, 0],
                                        [[512, 256], 2, 0]]
+
+
+# a scatter-gather call: out [2,4,8]; src, dst, w [2,1,256]; h [2,4,8]
+SG = [[[2, 4, 8], 4, 0], [[2, 1, 256], 4, 0], [[2, 1, 256], 4, 0],
+      [[2, 1, 256], 4, 0], [[2, 4, 8], 4, 0]]
+
+
+def test_kernels_roofline_reads_a_trace_that_holds_scatter_gather():
+    tr = dict(HAND, device_ops=HAND["device_ops"]
+              + [["scatter_gather_aggregate.2", 1100, 40, SG]])
+    s = tracing.reduce(tr)
+    assert s.kernel_calls["scatter_gather_aggregate"] == [SG]
+    peaks = {"bf16_flops_per_s": 1e12, "hbm_bytes_per_s": 1e9}
+    model = model_module("gcn")
+    ops, moved = flops.kernel_call("scatter_gather_aggregate", SG, model)
+    assert tracing.kernel_roofline(s, "scatter_gather_aggregate", model,
+                                   peaks) == pytest.approx(
+        100.0 * max(ops / 1e12, moved / 1e9) / 40e-9)
+    cell = type("Cell", (), {"model_module": lambda self: model})()
+    run = type("Run", (), {"trace": s, "cell": cell, "peaks": peaks})()
+    reader = harness.load_module(os.path.join(
+        REPO, "bench", "metrics", "kernels_roofline.py"), "test_kr")
+    shares = {k: tracing.kernel_roofline(s, k, model, peaks)
+              for k in s.kernel_seconds}
+    want = sum(shares[k] * s.kernel_seconds[k] for k in shares) \
+        / sum(s.kernel_seconds.values())
+    assert reader.read(run) == pytest.approx(want)
 
 
 RECORDED = sorted(glob.glob(os.path.join(REPO, "bench", "testdata",
@@ -124,3 +156,16 @@ def test_reduction_of_a_recorded_chip_trace(path):
     for k, v in want["kernel_seconds"].items():
         assert s.kernel_seconds[k] == pytest.approx(v)
     assert sum(s.kernel_seconds.values()) <= s.busy_s + 1e-12
+
+
+def test_recorded_chip_trace_reads_the_pinned_roofline():
+    """``fused_gnn_layer_roofline`` of the recorded GCN trace, as it read
+    before the counts moved into the model and kernel files (the ledger's
+    42.8 for the cell)."""
+    with open(os.path.join(REPO, "bench", "testdata",
+                           "gcn-flickr.zipf.trace.json")) as f:
+        s = tracing.reduce(json.load(f)["trace"])
+    with open(os.path.join(REPO, "bench", "peaks.json")) as f:
+        peaks = json.load(f)["TPU v5 lite"]
+    assert tracing.kernel_roofline(s, "fused_gnn_layer", model_module("gcn"),
+                                   peaks) == 42.81726095920053
