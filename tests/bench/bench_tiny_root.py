@@ -14,10 +14,22 @@ for _p in (REPO, os.path.join(REPO, "src")):
         sys.path.insert(0, _p)
 
 
+def _load(rel: str) -> dict:
+    with open(os.path.join(REPO, rel)) as f:
+        return json.load(f)
+
+
+def _kind(entry: dict) -> str:
+    """Model kind of a ``configs`` entry of BENCHMARK.json."""
+    return _load(entry["file"])["model"]["kind"]
+
+
 def tiny_config(kind: str) -> dict:
-    with open(os.path.join(REPO, "bench", "configs",
-                           f"{kind}-flickr.json")) as f:
-        c = json.load(f)
+    """The first configuration of ``kind`` in BENCHMARK.json, at a tiny
+    size."""
+    entry = next(c for c in _load("BENCHMARK.json")["configs"]
+                 if _kind(c) == kind)
+    c = _load(entry["file"])
     c["name"] = f"{kind}-tiny"
     c["model"].update(receptive_field=16, f_in=40, f_hidden=32, n_layers=2)
     c["graph"].update(num_vertices=1500, feature_dim=40, seed=3)
@@ -29,12 +41,15 @@ def tiny_config(kind: str) -> dict:
 
 def make_root(tmp: str, kinds=("gcn", "gat")) -> str:
     """``tmp`` laid out as a checkout: BENCHMARK.json naming one tiny
-    Zipf cell per model kind, and a copy of bench/ holding their files."""
+    Zipf cell per model kind, and a copy of bench/ holding their files.
+    A per-layer metric goes to the tiny cell of each kind that some cell
+    in its ``workloads`` has; one with no ``workloads`` to every cell."""
     root = os.path.join(str(tmp), "checkout")
     shutil.copytree(os.path.join(REPO, "bench"), os.path.join(root, "bench"),
                     ignore=shutil.ignore_patterns("__pycache__"))
-    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
-        bm = json.load(f)
+    bm = _load("BENCHMARK.json")
+    kind_of = {c["name"]: _kind(c) for c in bm["configs"]}
+    cell_kind = {w["name"]: kind_of[w["config"]] for w in bm["workloads"]}
     bm["configs"], bm["workloads"] = [], []
     for kind in kinds:
         name = f"{kind}-tiny"
@@ -46,11 +61,10 @@ def make_root(tmp: str, kinds=("gcn", "gat")) -> str:
         bm["workloads"].append({"name": f"{name}.zipf", "config": name,
                                 "traffic": "zipf-tiny", "chips": 1,
                                 "why": "test"})
-    cells = [w["name"] for w in bm["workloads"]]
     for m in bm["per_layer"]:
-        m["workloads"] = [c for c in cells
-                          if m["name"] != "gat_attention_roofline"
-                          or c.startswith("gat")]
+        if "workloads" in m:
+            has = {cell_kind[c] for c in m["workloads"]}
+            m["workloads"] = [f"{k}-tiny.zipf" for k in kinds if k in has]
     with open(os.path.join(root, "bench", "traffic", "zipf-tiny.json"),
               "w") as f:
         json.dump({"targets": {"kind": "zipf", "a": 1.1}, "rate_per_s": 40,

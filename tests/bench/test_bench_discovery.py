@@ -1,10 +1,12 @@
 """BENCHMARK.json against the harness: every name resolves to its file,
 and a new configuration, traffic mix or metric is found by adding files
 and entries alone."""
+import dataclasses
 import json
 import os
 import re
 import shutil
+import typing
 
 import numpy as np
 import pytest
@@ -36,7 +38,10 @@ def test_benchmark_file_keeps_to_its_shape():
         assert c["file"].startswith("bench/")
     for w in BM["workloads"]:
         assert set(w) == {"name", "config", "traffic", "chips", "why"}
-        assert w["chips"] == 1 and len(w["why"]) <= 200
+        assert w["chips"] in (1, 4) and len(w["why"]) <= 200
+    # at most half of the cells, rounded down, take four chips; one may
+    assert sum(w["chips"] == 4 for w in BM["workloads"]) <= max(
+        1, len(CELLS) // 2)
     for m in BM["end_to_end"]:
         assert 0.01 <= m["bound"] <= 0.25 and UNIT.match(m["unit"])
     layers = {}
@@ -92,8 +97,8 @@ def test_new_files_are_found_without_editing_any_other(tmp_path,
                           "reduced": [], "why": "test"})
     bm["workloads"].append({"name": "sage-tiny.zipf", "config": "sage-tiny",
                             "traffic": "zipf-tiny", "chips": 1, "why": "t"})
-    for m in bm["per_layer"]:
-        if m["name"] != "gat_attention_roofline":
+    for m in bm["per_layer"]:                 # the metrics GCN's cell has
+        if "gcn-tiny.zipf" in m.get("workloads", ()):
             m["workloads"].append("sage-tiny.zipf")
     bm["per_layer"].append({"name": "requests_seen", "unit": "count",
                             "better": "higher", "source": "host_clock",
@@ -151,17 +156,81 @@ def test_a_reader_that_disagrees_with_its_entry_is_refused(tmp_path):
         harness.load_cell(root, "gcn-tiny.zipf").metric_readers()
 
 
+def test_tiny_cells_take_the_metrics_of_their_kind(tmp_path):
+    """A tiny cell carries the per-layer metrics that BENCHMARK.json lists
+    for the cells of its model kind. For GCN and GAT that is the list the
+    tiny cells carried before metrics were chosen by kind: every metric,
+    ``gat_attention_roofline`` for GAT alone."""
+    root = make_root(tmp_path)
+    for kind in ("gcn", "gat"):
+        of_kind = [c for c in CELLS
+                   if harness.load_cell(REPO, c).model["kind"] == kind]
+        want = [m["name"] for m in BM["per_layer"]
+                if any(harness.applies(m, c) for c in of_kind)]
+        got = [m["name"] for m in
+               harness.load_cell(root, f"{kind}-tiny.zipf").per_layer]
+        assert got == want, kind
+        assert ("gat_attention_roofline" in got) == (kind == "gat")
+
+
+# The cells whose configs were built from nine ``model`` and five
+# ``serving`` keys named one by one, before every key reached its field.
+PINNED = ("gcn-flickr.zipf", "gat-flickr.zipf")
+
+
+def _field_type(hint):
+    """The type a config value of a field hinted ``hint`` takes, or
+    ``object`` where any will do."""
+    if typing.get_origin(hint) is typing.Union:
+        args = [a for a in typing.get_args(hint) if a is not type(None)]
+        hint = args[0] if len(args) == 1 else object
+    if typing.get_origin(hint) is tuple:
+        return tuple
+    return hint if isinstance(hint, type) else object
+
+
+def _assert_built_from(obj, values: dict, where: str):
+    """Each key of ``values`` reached the field of that name of ``obj``,
+    with the field's type; each field ``values`` leaves out kept its
+    default."""
+    hints = typing.get_type_hints(type(obj))
+    fields = dataclasses.fields(obj)
+    assert set(values) <= {f.name for f in fields}, where
+    for f in fields:
+        got, at = getattr(obj, f.name), f"{where}.{f.name}"
+        if f.name not in values:
+            default = (f.default if f.default is not dataclasses.MISSING
+                       else f.default_factory())
+            assert got == default, at
+        elif dataclasses.is_dataclass(got):
+            _assert_built_from(got, values[f.name], at)
+        else:
+            want = values[f.name]
+            assert got == (tuple(want) if isinstance(want, list) else want), at
+            kind = _field_type(hints[f.name])
+            exact = kind in (int, float, str, bool, tuple)
+            assert got is None or (type(got) is kind if exact
+                                   else isinstance(got, kind)), at
+
+
 @pytest.mark.parametrize("cell", CELLS)
 def test_program_configs_are_built_as_before(cell):
-    """Every key of ``model`` and ``serving`` reaches the program: the
-    configs come out field by field as the nine and five keys named one
-    by one built them."""
-    import dataclasses
+    """Every key of ``model`` and ``serving`` reaches the program: each
+    field of ``GNNConfig`` and ``ServingConfig`` (and its ``store``) is
+    the configured value in the field's type, or its default where the
+    config does not set it. The accepted cells' configs also come out
+    field by field as the nine and five keys named one by one built
+    them."""
     from repro.core.config import ServingConfig
     from repro.gnn.model import GNNConfig
     from repro.store import StorePolicy
     c = harness.load_cell(REPO, cell)
     m, s = c.model, c.config["serving"]
+    got_g, got_s = harness.program_configs(c)
+    _assert_built_from(got_g, m, "model")
+    _assert_built_from(got_s, s, "serving")
+    if cell not in PINNED:
+        return
     want_g = GNNConfig(kind=m["kind"], n_layers=int(m["n_layers"]),
                        receptive_field=int(m["receptive_field"]),
                        f_in=int(m["f_in"]), f_hidden=int(m["f_hidden"]),
@@ -172,7 +241,6 @@ def test_program_configs_are_built_as_before(cell):
                            mode=s["mode"], num_threads=int(s["num_threads"]),
                            max_wait_s=float(s["max_wait_s"]),
                            store=StorePolicy(**s["store"]))
-    got_g, got_s = harness.program_configs(c)
     for got, want in ((got_g, want_g), (got_s, want_s),
                       (got_s.store, want_s.store)):
         for f in dataclasses.fields(want):
@@ -180,11 +248,14 @@ def test_program_configs_are_built_as_before(cell):
             assert a == b and type(a) is type(b), f.name
 
 
-@pytest.mark.parametrize("group,key", [("model", "aggregators"),
-                                       ("serving", "lanes"),
-                                       ("serving.store", "shards")])
-def test_a_config_key_the_program_lacks_is_refused_at_load(tmp_path, group,
-                                                           key):
+@pytest.mark.parametrize("group", ["model", "serving", "serving.store"])
+def test_a_config_key_the_program_lacks_is_refused_at_load(tmp_path, group):
+    from repro.core.config import ServingConfig
+    from repro.gnn.model import GNNConfig
+    from repro.store import StorePolicy
+    key = "no_such_key_17"
+    for cls in (GNNConfig, ServingConfig, StorePolicy):
+        assert key not in {f.name for f in dataclasses.fields(cls)}
     root = make_root(tmp_path, kinds=("gcn",))
     path = os.path.join(root, "bench", "configs", "gcn-tiny.json")
     with open(path) as f:
